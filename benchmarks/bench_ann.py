@@ -33,6 +33,7 @@ import numpy as np
 from benchmarks.common import emit_value
 from repro.core import make_task, pretrain_model
 from repro.core.task import TaskSpec
+from repro.device import enable_compile_cache
 from repro.engine import AnnConfig, EngineConfig, MorphingServer, \
     MorphingSession
 from repro.engine.serve import _SHARE_TABLE
@@ -256,4 +257,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

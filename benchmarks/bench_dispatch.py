@@ -38,6 +38,7 @@ import numpy as np
 from benchmarks.common import emit_value
 from repro.core import make_task, pretrain_model
 from repro.core.task import TaskSpec
+from repro.device import enable_compile_cache
 from repro.engine import DispatchServer, MorphingSession, PlacementPolicy
 
 N_ROWS = 1500
@@ -246,4 +247,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

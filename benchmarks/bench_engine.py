@@ -25,6 +25,7 @@ import numpy as np
 from benchmarks.common import emit, emit_value, timeit
 from repro.core import (ModelSelector, TaskFeaturizer, build_tasks,
                         build_zoo, make_task, transfer_matrix)
+from repro.device import default_interpret, enable_compile_cache
 from repro.engine import MorphingSession
 from repro.pipeline.backend import JaxBackend
 from repro.pipeline.operators import groupby_agg
@@ -203,8 +204,7 @@ def run(n_rows: int = N_ROWS, backends=("numpy", "jax"),
         emit_value("engine.speedup_jax_vs_numpy", speedup,
                    "warm rows/s ratio")
         if n_rows >= MIN_ROWS_FOR_SPEEDUP_ASSERT:
-            import jax
-            interpret = jax.default_backend() != "tpu"
+            interpret = default_interpret()
             target = (INTERPRET_SANITY_SPEEDUP if interpret
                       else TARGET_SPEEDUP)
             assert speedup >= target, (
@@ -237,4 +237,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

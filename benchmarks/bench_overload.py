@@ -45,6 +45,7 @@ import numpy as np
 from benchmarks.common import emit_value
 from repro.core import make_task, pretrain_model
 from repro.core.task import TaskSpec
+from repro.device import enable_compile_cache
 from repro.engine import MorphingServer, MorphingSession
 from repro.pipeline import AdmissionPolicy, Rejected, RequestError
 from repro.training.fault import FaultInjector, InjectedFault
@@ -401,4 +402,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -50,12 +50,14 @@ def _ensure_host_devices(n: int) -> None:
 
 _ensure_host_devices(DEVICE_COUNT)
 
+import jax                                               # noqa: E402
 import numpy as np                                       # noqa: E402
 from concurrent.futures import ThreadPoolExecutor        # noqa: E402
 
 from benchmarks.common import emit_value                 # noqa: E402
 from repro.core import make_task, pretrain_model         # noqa: E402
 from repro.core.task import TaskSpec                     # noqa: E402
+from repro.device import enable_compile_cache            # noqa: E402
 from repro.engine import MorphingServer, MorphingSession  # noqa: E402
 
 N_ROWS = 4000
@@ -138,7 +140,11 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
     per_devices = {}
     outs_by_devices = {}
     for devices in (1, DEVICE_COUNT):
-        server = _make_server(zoo, table, sample, devices)
+        # the pool refuses more devices than exist: under run.py, after
+        # another bench fixed jax's topology, the mesh leg runs on what
+        # there is and records it as devices_effective
+        server = _make_server(zoo, table, sample,
+                              min(devices, jax.device_count()))
         rows_total = _rows_served(server.session, stmts)
         with server:
             wall, outs, st = _bench(server, stmts, concurrency)
@@ -216,4 +222,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

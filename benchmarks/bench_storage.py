@@ -33,6 +33,7 @@ from pathlib import Path
 import numpy as np
 
 from benchmarks.common import emit_value, timeit
+from repro.device import enable_compile_cache
 from repro.storage import (ApiModelRegistry, BlobStore, Catalog,
                            DecoupledStore)
 
@@ -279,4 +280,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

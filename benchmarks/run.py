@@ -13,6 +13,8 @@ import os
 import sys
 import traceback
 
+from repro.device import enable_compile_cache
+
 MODULES = [
     "bench_series",      # Fig 6
     "bench_nlp",         # Fig 7
@@ -56,4 +58,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -11,6 +11,7 @@ import numpy as np
 from repro.core import (ModelSelector, TaskFeaturizer, TaskRegistry,
                         TaskSpec, build_tasks, build_zoo, make_task,
                         transfer_matrix)
+from repro.device import enable_compile_cache
 from repro.pipeline import Dag, Node, PipelineExecutor, filter_op, groupby_agg
 
 
@@ -66,4 +67,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

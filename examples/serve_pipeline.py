@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from repro.configs import smoke_config
+from repro.device import enable_compile_cache
 from repro.launch.serve import ServingEngine
 from repro.models import build_model
 from repro.pipeline import OpProfile, choose_batch_size
@@ -49,4 +50,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

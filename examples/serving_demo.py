@@ -18,9 +18,9 @@ bytes are read from disk (see docs/serving.md):
   PYTHONPATH=src python examples/serving_demo.py --delta
 
 With ``--workers N`` the same traffic runs through the multi-process
-dispatch tier instead: a ``DispatchServer`` front door spawns N worker
-processes over the shared store, routes coalesced batches to them as
-leases, and keeps each trunk on as few workers as its load needs
+dispatch tier instead: a ``DispatchServer`` front door spawns N numpy
+worker processes over the shared store, routes coalesced batches to
+them as leases, and keeps each trunk on as few workers as its load needs
 (``--delta --workers 2`` shows the whole fleet staged on one worker's
 shared embed lane). The stats dump covers placement, leases, and the
 per-worker aggregates (see docs/serving.md "Dispatch tier"):
@@ -33,7 +33,9 @@ import numpy as np
 
 from repro.core import (ModelSelector, TaskFeaturizer, build_tasks,
                         build_zoo, make_task, transfer_matrix)
-from repro.engine import DispatchServer, MorphingServer, MorphingSession
+from repro.device import enable_compile_cache
+from repro.engine import (DispatchServer, EngineConfig, MorphingServer,
+                          MorphingSession)
 
 N_FINETUNES = 3
 
@@ -46,7 +48,11 @@ def main(delta: bool = False, workers: int = 0) -> None:
     feats = np.stack([fz.features(t.X, t.y) for t in history])
     sel = ModelSelector(k=6, n_anchors=3).fit_offline(V, feats, zoo=zoo)
 
-    sess = MorphingSession(selector=sel, zoo=zoo, model_store="decoupled")
+    # the dispatch tier's front door runs no inference, and its workers
+    # are numpy processes: a chip belongs to one process, so neither the
+    # front door nor N workers may each take it
+    sess = MorphingSession(selector=sel, zoo=zoo, config=EngineConfig(
+        model_store="decoupled", backend="numpy" if workers else "auto"))
     rng = np.random.default_rng(0)
     n = 3000
     sess.register_table("reviews", {
@@ -140,6 +146,7 @@ def main(delta: bool = False, workers: int = 0) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--delta", action="store_true",
                     help="serve a fine-tune fleet (base + "

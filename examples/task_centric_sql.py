@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core import (ModelSelector, TaskFeaturizer, build_tasks,
                         build_zoo, make_task, transfer_matrix)
+from repro.device import enable_compile_cache
 from repro.engine import MorphingSession
 
 
@@ -106,6 +107,7 @@ def main(delta: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--delta", action="store_true",
                     help="add a fine-tune delta variant sharing the "

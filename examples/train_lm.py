@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs import smoke_config
 from repro.data import DataConfig, SyntheticCorpus
+from repro.device import enable_compile_cache
 from repro.models import build_model
 from repro.storage import CheckpointManager
 from repro.training import OptimizerConfig, init_state, make_train_step
@@ -68,4 +69,5 @@ def main(steps: int = 250) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

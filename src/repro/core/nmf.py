@@ -50,7 +50,11 @@ def nmf(V: jax.Array, k: int, *, iters: int = 300,
         loss = jnp.sum(resid * resid)
         return (W_new, H_new), loss
 
-    (W, H), losses = jax.lax.scan(step, (W, H), None, length=iters)
+    # float32 matmuls on every platform: at the TPU's default precision
+    # (one bf16 pass) the factors drift far enough from the CPU's to
+    # change which model the selector picks for a task
+    with jax.default_matmul_precision("float32"):
+        (W, H), losses = jax.lax.scan(step, (W, H), None, length=iters)
     return NMFResult(W, H, losses)
 
 

@@ -18,8 +18,6 @@ from typing import Any, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import axis_size
-
 
 class EFState(NamedTuple):
     residual: Any  # pytree of f32 residuals, like grads
@@ -53,7 +51,7 @@ def compressed_psum(grads, ef: EFState, axis_name: str,
             lambda g: jax.lax.pmean(g.astype(jnp.float32), axis_name), grads)
         return red, ef
 
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         g = g.astype(jnp.float32) + r

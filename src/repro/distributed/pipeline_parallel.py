@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import axis_size
-
 
 def gpipe_apply(stage_fn: Callable, stage_params, microbatches: jax.Array,
                 *, axis: str) -> jax.Array:
@@ -25,7 +23,7 @@ def gpipe_apply(stage_fn: Callable, stage_params, microbatches: jax.Array,
     layer group. microbatches: [M, mb, ...] (replicated across stages).
     Returns [M, mb, ...] outputs of the final stage (replicated).
     """
-    S = axis_size(axis)
+    S = jax.lax.axis_size(axis)
     sid = jax.lax.axis_index(axis)
     M = microbatches.shape[0]
     T = M + S - 1

@@ -138,35 +138,12 @@ def serving_weight_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
     return named_sharding(mesh, axes, serving_rules())
 
 
-def axis_size(axis_name: str) -> int:
-    """Version-portable mapped-axis size (inside shard_map bodies).
-
-    jax >= 0.5 has ``jax.lax.axis_size``; on 0.4.x the same static size
-    comes from ``jax.core.axis_frame``.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.core as _core
-    return int(_core.axis_frame(axis_name))
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs,
-              check_replication: bool = False):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.5 exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map``
-    (``check_rep``). Call sites in this repo always want the check off —
-    Pallas calls and collectives inside the body defeat the checker —
-    so both spellings are bridged behind one keyword.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             check_vma=check_replication)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_replication)
+def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication (vma) check off: every call
+    site in this repo has a Pallas call or collectives in its body, and
+    those defeat the checker."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

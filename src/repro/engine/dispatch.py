@@ -54,6 +54,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import multiprocessing as mp
+import os
 import queue as queue_mod
 import threading
 import time
@@ -63,6 +64,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from repro.device import held_to_cpu
 from repro.engine.config import EngineConfig
 from repro.engine.serve import (MorphingServer, ServeResult, ServerStats,
                                 _LANE_BATCH_CANDIDATES)
@@ -381,9 +383,13 @@ class DispatchServer:
     by ``model_id``.
 
     ``workers`` defaults to ``EngineConfig.workers``. ``worker_backend``
-    overrides the workers' backend flavour (the front door's own
-    backends never run inference — ``'numpy'`` workers give real
-    multi-core scaling on CPU hosts and skip the jax import at spawn).
+    overrides the workers' backend flavour (``'numpy'`` workers give
+    real multi-core scaling on CPU hosts and skip the jax import at
+    spawn). The front door never runs inference, so a session it builds
+    itself is a numpy session. A chip belongs to one process: workers
+    that would run jax are refused unless JAX is held to the CPU
+    (``JAX_PLATFORMS=cpu``) — N children cannot share the device, nor
+    take it from a parent that already holds it.
     Workers auto-calibrate through the on-disk memo
     (``EngineConfig.calib_memo_path``, defaulted to a file under the
     shared root) so N processes pay the two-point probe once.
@@ -405,14 +411,31 @@ class DispatchServer:
                  start_timeout_s: float = 120.0,
                  **session_kw):
         if session is None:
-            cfg = config or EngineConfig(model_store="decoupled")
-            session = MorphingSession(config=cfg, **session_kw)
+            # config kwargs shape the workers; the rest (selector, zoo,
+            # root) build the front door's own session
+            fields = {f.name for f in dataclasses.fields(EngineConfig)}
+            cfg = (config or EngineConfig(model_store="decoupled")
+                   ).overlaid({k: session_kw.pop(k) for k in
+                               list(session_kw) if k in fields})
+            session = MorphingSession(
+                config=dataclasses.replace(cfg, backend="numpy"),
+                **session_kw)
+        else:
+            cfg = session.config
         self.session = session
-        cfg = session.config
         if session.model_store != "decoupled":
             raise ValueError(
                 "DispatchServer requires model_store='decoupled': workers "
                 "resolve served models from the shared store root")
+        worker_kind = worker_backend or cfg.backend
+        if worker_kind != "numpy" and not held_to_cpu():
+            raise RuntimeError(
+                f"DispatchServer workers with backend {worker_kind!r} "
+                "would each need the accelerator, and a chip belongs to "
+                "one process (JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS', '')!r}). Use "
+                "worker_backend='numpy', or serve the chip from one "
+                "process with MorphingServer")
         self.workers_requested = int(
             workers if workers is not None else cfg.workers)
         if self.workers_requested < 1:
@@ -434,7 +457,7 @@ class DispatchServer:
         # the shared calibration memo (first prober writes, rest read)
         self._worker_cfg = dataclasses.replace(
             cfg,
-            backend=worker_backend or cfg.backend,
+            backend=worker_kind,
             model_store="decoupled",
             policy=cfg.policy or AdmissionPolicy(),
             calib_memo_path=(cfg.calib_memo_path or

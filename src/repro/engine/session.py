@@ -40,8 +40,9 @@ from repro.pipeline.backend import (ExecutionBackend, JaxBackend,
                                     make_backends)
 from repro.pipeline.batcher import BatcherStats
 from repro.pipeline.cost import (HardwareProfile, OpProfile, calibrate,
-                                 delta_staged_profile, load_profile_memo,
-                                 profile_for_model, profile_memo_fingerprint,
+                                 delta_staged_profile, device_profile,
+                                 load_profile_memo, profile_for_model,
+                                 profile_memo_fingerprint,
                                  store_profile_memo)
 from repro.pipeline.operators import (Batch, aggregate, batch_len,
                                       groupby_aggs)
@@ -205,11 +206,10 @@ def _fast_profile(backend: ExecutionBackend, device: str,
         # mesh spans. The probe shares the live mesh — building a second
         # mesh over the same devices would be pure overhead.
         key = ("jax-mesh", backend.interpret, backend.device_count)
-        probe_fn = lambda: MeshJaxBackend(  # noqa: E731
-            mesh=backend.mesh, interpret=backend.interpret)
+        probe_fn = lambda: MeshJaxBackend(mesh=backend.mesh)  # noqa: E731
     elif isinstance(backend, JaxBackend):
         key = ("jax", backend.interpret)
-        probe_fn = lambda: JaxBackend(interpret=backend.interpret)  # noqa: E731
+        probe_fn = JaxBackend
     elif isinstance(backend, NumpyBackend):
         key = ("numpy", None)
         probe_fn = NumpyBackend
@@ -218,8 +218,9 @@ def _fast_profile(backend: ExecutionBackend, device: str,
     with _FAST_CALIB_LOCK:
         prof = _FAST_CALIB_CACHE.get(key)
         if prof is None and memo_path:
-            # disk memo: the fingerprint embeds jax version/device count
-            # (cpu count for host backends), so stale entries just miss
+            # disk memo: the fingerprint embeds jax version, platform,
+            # device kind and count (cpu count for host backends), so
+            # stale entries just miss
             prof = load_profile_memo(memo_path).get(
                 profile_memo_fingerprint(key))
             if prof is not None:
@@ -292,15 +293,20 @@ class MorphingSession:
         self.zoo = zoo or []
         self.devices = cfg.devices
         # the pool is dict-compatible with the old registry; with
-        # device_count > 1 its jax annotation spans a mesh (clamped to
-        # the devices jax actually exposes — a clamp to 1 falls back to
-        # the parity-exact single-device backends)
+        # device_count > 1 its jax annotation spans a mesh of exactly
+        # that many devices (more than jax exposes raises)
         self.backends = make_backends(
             cfg.backend, devices=cfg.devices,
             device_count=cfg.device_count)
         self.device_count = getattr(self.backends, "device_count", 1)
         self.enable_share = cfg.enable_share
-        self.hw: Optional[Dict[str, HardwareProfile]] = None
+        # every jax-served annotation plans from its own device: the
+        # published peaks of its device_kind (an unknown kind raises),
+        # replaced by measured numbers when the session calibrates
+        self.hw: Optional[Dict[str, HardwareProfile]] = {
+            dev: device_profile(dev, b.device_kind)
+            for dev, b in self.backends.items()
+            if isinstance(b, JaxBackend)} or None
         self.chunk_rows = cfg.chunk_rows
         self.max_inflight = cfg.max_inflight
         self.workers = cfg.workers
@@ -314,17 +320,16 @@ class MorphingSession:
         """Fast calibration at construction (ROADMAP open item): use the
         process-wide memoized profiles so Eq. 10/11 planning starts from
         measured numbers without each session paying a measurement. Full
-        per-session measurement stays available via :meth:`calibrate`."""
-        try:
-            hw = {}
-            for dev, b in self.backends.items():
-                prof = _fast_profile(b, dev,
-                                     memo_path=self.config.calib_memo_path)
-                if prof is not None:
-                    hw[dev] = prof
-            self.hw = hw or None
-        except Exception:            # calibration must never block startup
-            self.hw = None
+        per-session measurement stays available via :meth:`calibrate`.
+        A calibration that fails raises: a session never plans from
+        numbers it could not measure without saying so."""
+        hw = dict(self.hw or {})
+        for dev, b in self.backends.items():
+            prof = _fast_profile(b, dev,
+                                 memo_path=self.config.calib_memo_path)
+            if prof is not None:
+                hw[dev] = prof
+        self.hw = hw or None
 
     # -- catalog-facing API ----------------------------------------------
     def register_table(self, name: str, table: Batch) -> None:

@@ -1,23 +1,19 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container) and False on TPU —
-the kernels are the TPU-target implementation; interpret mode executes the
-same kernel bodies in Python for correctness validation.
+``interpret`` defaults to :func:`repro.device.default_interpret`: False on
+a TPU, True only where JAX is held to the CPU (the test path), an error
+anywhere else. Interpret mode executes the same kernel bodies in Python
+for correctness validation.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
+from repro.device import default_interpret as _default_interpret
 from repro.kernels.decode_attention import decode_attention as _decode
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.fused_embed import fused_embed as _embed
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True,

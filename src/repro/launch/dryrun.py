@@ -23,6 +23,7 @@ from repro.analysis.hlo import model_flops, roofline_terms
 from repro.analysis.hlo_cost import analyze_hlo
 from repro.analysis.jaxpr_flops import count_flops
 from repro.configs import SHAPES, get_config, list_archs, shapes_for
+from repro.device import enable_compile_cache
 from repro.distributed.sharding import (axis_rules, rules_for_config,
                                         tree_shardings)
 from repro.launch.mesh import dp_size, make_production_mesh
@@ -239,4 +240,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

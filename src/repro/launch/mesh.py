@@ -7,17 +7,10 @@ multi-pod: 2x16x16 = 512 chips ("pod","data","model").
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                     # jax >= 0.5 explicit axis types
-    from jax.sharding import AxisType
-except ImportError:      # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -35,10 +28,16 @@ def make_host_mesh(data: int = 2, model: int = 4) -> Mesh:
 
 def make_serving_mesh(device_count: int) -> Mesh:
     """1-D ("data",) mesh over the first ``device_count`` devices — the
-    mesh the serving backend pool's data-parallel embed lanes span."""
+    mesh the serving backend pool's data-parallel embed lanes span.
+    Asking for more devices than jax exposes is an error, never a
+    quietly narrower mesh."""
     import numpy as np
     avail = jax.devices()
-    n = max(1, min(int(device_count), len(avail)))
+    n = int(device_count)
+    if not 1 <= n <= len(avail):
+        raise ValueError(
+            f"device_count={n}, but jax exposes {len(avail)} "
+            f"{avail[0].platform} device(s)")
     return Mesh(np.array(avail[:n]), ("data",))
 
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.device import enable_compile_cache
 from repro.models import build_model
 from repro.pipeline import OpProfile, choose_batch_size
 from repro.training import make_serve_step
@@ -86,4 +87,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

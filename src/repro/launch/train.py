@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, SyntheticCorpus
+from repro.device import enable_compile_cache
 from repro.distributed.sharding import axis_rules, rules_for_config, tree_shardings
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import batch_axes, build_model
@@ -107,4 +108,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

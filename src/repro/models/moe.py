@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import axis_size
 from repro.models.spec import P
 
 
@@ -144,7 +143,7 @@ def moe_ragged_local(cfg, p: dict, x: jax.Array, *,
     E_local = wi.shape[0]
     ep = 1
     if ep_axis is not None:
-        ep = axis_size(ep_axis)
+        ep = jax.lax.axis_size(ep_axis)
         rank = jax.lax.axis_index(ep_axis)
         local_id = idx - rank * E_local
     else:
@@ -214,7 +213,7 @@ def moe_batched_local(cfg, p: dict, x: jax.Array, *,
     E_local = wi.shape[0]
     ep = 1
     if ep_axis is not None:
-        ep = axis_size(ep_axis)
+        ep = jax.lax.axis_size(ep_axis)
         rank = jax.lax.axis_index(ep_axis)
         local_id = idx - rank * E_local
     else:

@@ -12,8 +12,8 @@ three responsibilities for embed/predict operators:
   ``ZooModel`` forward pass (all four modes: linear/radial/relu/proj1d)
   plus the score head into ``jax.jit``-compiled functions. The linear
   mode routes through the fused normalize+project+tanh Pallas kernel
-  (``repro.kernels.fused_embed``): interpret mode on CPU, real Pallas on
-  TPU;
+  (``repro.kernels.fused_embed``): compiled on a TPU, interpret mode
+  only where JAX is held to the CPU (``repro.device.default_interpret``);
 - **shape bucketing** — ragged chunk row counts are padded to the next
   power of two and sliced on return, so a whole query triggers at most
   O(log n) compilations instead of one per distinct chunk length.
@@ -201,13 +201,15 @@ class NumpyBackend(ExecutionBackend):
 
 @dataclass
 class StagedModel:
-    """One resolved model, staged: device-resident weights + jitted fns."""
+    """One resolved model, staged: device-resident weights + jitted fns
+    that take the batch and then the weights."""
     version: str
     mode: str
     in_dim: int
     out_dim: int
-    features_fn: Callable            # [B, in_dim] -> [B, out_dim]
-    predict_fn: Callable             # [B, in_dim] -> [B]
+    features_fn: Callable            # [B, in_dim], *W -> [B, out_dim]
+    predict_fn: Callable             # [B, in_dim], *W -> [B]
+    weights: Tuple[Any, ...] = ()
     seen_shapes: Set[Tuple[str, int]] = field(default_factory=set)
 
 
@@ -218,22 +220,29 @@ def _next_pow2(n: int) -> int:
 class JaxBackend(ExecutionBackend):
     """jit-compiled device path with shape bucketing + one-time staging.
 
-    ``interpret`` defaults to True off-TPU (kernels run in Pallas
-    interpret mode under jit) and False on TPU. Whole chunks run as one
-    device call — the bucketing supersedes host-side window batching, so
+    ``interpret`` is set by the platform (:func:`repro.device.
+    default_interpret`): False on a TPU; True where JAX is held to the
+    CPU, and then the reported flavour (``name``, which lands in
+    ``QueryReport.backend_of``) says so. Whole chunks run as one device
+    call — the bucketing supersedes host-side window batching, so
     ``batch_size`` annotations are telemetry-only on this backend.
+    The matmuls of every trunk mode run at ``Precision.HIGHEST``, so
+    the staged forward agrees with the float32 numpy oracle on the chip.
     """
 
     name = "jax"
 
-    def __init__(self, *, interpret: Optional[bool] = None,
-                 min_bucket: int = 32, block_rows: int = 256):
+    def __init__(self, *, min_bucket: int = 32, block_rows: int = 256):
         import jax  # deferred so numpy-only paths never pay the import
+
+        from repro.device import default_interpret
 
         super().__init__()
         self._jax = jax
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else bool(interpret))
+        self.interpret = default_interpret()
+        if self.interpret:
+            self.name = f"{type(self).name}-interpret"
+        self.device_kind = jax.devices()[0].device_kind
         self.min_bucket = min_bucket
         self.block_rows = block_rows
         self._staged: Dict[str, StagedModel] = {}
@@ -259,10 +268,14 @@ class JaxBackend(ExecutionBackend):
         weights are already device-resident (:meth:`_put_weight`).
         Weights are explicit arguments — not closure captures — so the
         mesh subclass can hand them to ``shard_map`` with replicated
-        in_specs while the batch splits over the mesh.
+        in_specs while the batch splits over the mesh, and so the
+        compiled forward lowers from shapes alone.
         """
         jnp = self._jax.numpy
         from repro.kernels.fused_embed import fused_embed
+
+        def dot(a, b):
+            return jnp.dot(a, b, precision=self._jax.lax.Precision.HIGHEST)
 
         mode = zoo_model.mode
         W = self._put_weight(zoo_model.W)
@@ -280,13 +293,13 @@ class JaxBackend(ExecutionBackend):
             out_dim = int(zoo_model.W.shape[1])
 
             def raw(X, W):
-                return jnp.maximum(X @ W, 0.0)
+                return jnp.maximum(dot(X, W), 0.0)
             return mode, in_dim, out_dim, raw, (W,)
         if mode == "proj1d":
             out_dim = 2 * int(zoo_model.W.shape[1])
 
             def raw(X, W):
-                Z = X @ W
+                Z = dot(X, W)
                 return jnp.tanh(jnp.concatenate([Z, Z ** 2 - 1.0], axis=1))
             return mode, in_dim, out_dim, raw, (W,)
         # linear -> fused normalize+project+tanh Pallas kernel
@@ -300,13 +313,13 @@ class JaxBackend(ExecutionBackend):
         return mode, in_dim, out_dim, raw, (W,)
 
     def _compile_forward(self, raw: Callable,
-                         weights: Tuple[Any, ...]) -> Tuple[Callable,
-                                                            Callable]:
-        """(features_fn, predict_fn) from the raw forward. Overridden by
-        the mesh subclass to split the batch axis across devices."""
+                         n_weights: int) -> Tuple[Callable, Callable]:
+        """(features_fn, predict_fn) from the raw forward; both take
+        ``(X, *weights)``. Overridden by the mesh subclass to split the
+        batch axis across devices."""
         jax, jnp = self._jax, self._jax.numpy
-        return (jax.jit(lambda X: raw(X, *weights)),
-                jax.jit(lambda X: raw(X, *weights)
+        return (jax.jit(raw),
+                jax.jit(lambda X, *w: raw(X, *w)
                         .astype(jnp.float32).mean(axis=1)))
 
     def stage(self, version: str, zoo_model) -> StagedModel:
@@ -314,10 +327,11 @@ class JaxBackend(ExecutionBackend):
             if version in self._staged:
                 return self._staged[version]
         mode, in_dim, out_dim, raw, weights = self._raw_forward(zoo_model)
-        features_fn, predict_fn = self._compile_forward(raw, weights)
+        features_fn, predict_fn = self._compile_forward(raw, len(weights))
         staged = StagedModel(
             version=version, mode=mode, in_dim=in_dim, out_dim=out_dim,
-            features_fn=features_fn, predict_fn=predict_fn)
+            features_fn=features_fn, predict_fn=predict_fn,
+            weights=weights)
         with self._lock:
             if version not in self._staged:   # lost race: first stage wins
                 self._staged[version] = staged
@@ -373,7 +387,7 @@ class JaxBackend(ExecutionBackend):
                 staged.seen_shapes.add(key)
         if new_shape and self.on_compile is not None:
             self.on_compile(staged.version, key)
-        out = np.asarray(fn(Xb))
+        out = np.asarray(fn(Xb, *staged.weights))
         return out[:n]
 
     def _features(self, spec: InferSpec, X: np.ndarray) -> np.ndarray:
@@ -442,10 +456,8 @@ class MeshJaxBackend(JaxBackend):
     name = "jax-mesh"
 
     def __init__(self, mesh=None, *, device_count: Optional[int] = None,
-                 interpret: Optional[bool] = None, min_bucket: int = 32,
-                 block_rows: int = 256):
-        super().__init__(interpret=interpret, min_bucket=min_bucket,
-                         block_rows=block_rows)
+                 min_bucket: int = 32, block_rows: int = 256):
+        super().__init__(min_bucket=min_bucket, block_rows=block_rows)
         jax = self._jax
         if mesh is None:
             from repro.launch.mesh import make_serving_mesh
@@ -463,26 +475,26 @@ class MeshJaxBackend(JaxBackend):
         return self._jax.device_put(
             a, serving_weight_sharding(self.mesh, a.ndim))
 
-    def _compile_forward(self, raw, weights):
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+    def _compile_forward(self, raw, n_weights):
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.distributed.sharding import serving_batch_sharding
+        from repro.distributed.sharding import (serving_batch_sharding,
+                                                shard_map)
         jax, jnp = self._jax, self._jax.numpy
         # batch rows split over "data"; weights replicated on every
-        # device (they were staged that way) — check_rep off because the
-        # Pallas fused-embed call defeats the replication checker
+        # device (they were staged that way); the helper leaves the
+        # replication check off because the Pallas fused-embed call
+        # defeats it
         sharded = shard_map(
             raw, mesh=self.mesh,
-            in_specs=(P("data"),) + (P(),) * len(weights),
-            out_specs=P("data"), check_rep=False)
-        x_sharding = serving_batch_sharding(self.mesh)
-        features_fn = jax.jit(lambda X: sharded(X, *weights),
-                              in_shardings=x_sharding)
+            in_specs=(P("data"),) + (P(),) * n_weights,
+            out_specs=P("data"))
+        in_shardings = ((serving_batch_sharding(self.mesh),)
+                        + (NamedSharding(self.mesh, P()),) * n_weights)
+        features_fn = jax.jit(sharded, in_shardings=in_shardings)
         predict_fn = jax.jit(
-            lambda X: sharded(X, *weights)
-            .astype(jnp.float32).mean(axis=1),
-            in_shardings=x_sharding)
+            lambda X, *w: sharded(X, *w).astype(jnp.float32).mean(axis=1),
+            in_shardings=in_shardings)
         return features_fn, predict_fn
 
     def _bucket_for(self, n: int) -> int:
@@ -495,8 +507,7 @@ class MeshJaxBackend(JaxBackend):
         """A fresh single-device backend of the same flavour, so
         ``cost.calibrate`` can report the per-device rate next to the
         mesh-aggregate rate it measures through this backend."""
-        return JaxBackend(interpret=self.interpret,
-                          min_bucket=self.min_bucket,
+        return JaxBackend(min_bucket=self.min_bucket,
                           block_rows=self.block_rows)
 
 
@@ -547,16 +558,14 @@ class BackendPool(Dict[str, ExecutionBackend]):
 
 def _mesh_jax_backend(device_count: int) -> Tuple[Optional[JaxBackend],
                                                   int, Any]:
-    """(backend, effective device count, mesh) for an accelerator slot.
+    """(backend, device count, mesh) for an accelerator slot.
 
-    ``device_count`` is clamped to the devices jax actually exposes
-    (simulated host devices count via ``xla_force_host_platform_
-    device_count``); a clamp to one device degrades to the plain
-    single-device :class:`JaxBackend` — byte-identical to the
-    pre-mesh path.
+    One device is the plain single-device :class:`JaxBackend`; more
+    span a :class:`MeshJaxBackend` (simulated host devices count, via
+    ``xla_force_host_platform_device_count``). Asking for more devices
+    than jax exposes raises (:func:`repro.launch.mesh.make_serving_mesh`).
     """
-    import jax
-    n = max(1, min(int(device_count), len(jax.devices())))
+    n = int(device_count)
     if n == 1:
         return JaxBackend(), 1, None
     b = MeshJaxBackend(device_count=n)
@@ -568,14 +577,17 @@ def make_backends(kind: str = "auto",
                   device_count: int = 1) -> BackendPool:
     """Build the placement-aware backend pool.
 
-    'auto'  -> host: numpy, tpu: jax (numpy fallback if jax is missing)
+    'auto'  -> host: numpy, tpu: jax
     'numpy' -> every device runs the host numpy path
     'jax'   -> every device runs the jitted path (CPU = interpret kernels)
 
+    A jax backend that cannot be built raises: the pool never serves
+    the ``"tpu"`` annotation with numpy behind the caller's back.
     ``device_count > 1`` asks for a mesh: the jax-backed annotations are
-    served by one :class:`MeshJaxBackend` spanning ``min(device_count,
-    jax.device_count())`` devices. The numpy path has no devices to
-    span, so a pure-numpy pool always reports ``device_count == 1``.
+    served by one :class:`MeshJaxBackend` spanning exactly that many
+    devices (more than jax exposes is an error). The numpy path has no
+    devices to span, so a pure-numpy pool always reports
+    ``device_count == 1``.
     """
     np_b = NumpyBackend()
     if kind == "numpy":
@@ -590,10 +602,7 @@ def make_backends(kind: str = "auto",
     n_eff, mesh = 1, None
     for d in devices:
         if d == "tpu":
-            try:
-                reg[d], n_eff, mesh = _mesh_jax_backend(device_count)
-            except Exception:                 # jax unavailable: degrade
-                reg[d] = np_b
+            reg[d], n_eff, mesh = _mesh_jax_backend(device_count)
         else:
             reg[d] = np_b
     return BackendPool(reg, kind=kind, device_count=n_eff, mesh=mesh)

@@ -20,12 +20,15 @@ Equation map (each implemented here by name):
   and latency bound (:func:`choose_batch_size`); :func:`split_profile`
   sizes the serving embed and head stages separately.
 
-Devices: 'host' (CPU relational ops + small models), 'tpu' (v5e chip),
-'api' (remote endpoint). See ``docs/architecture.md`` for where each
-decision lands in the dataflow.
+Devices: 'host' (CPU relational ops + small models), 'tpu' (the
+accelerator), 'api' (remote endpoint). See ``docs/architecture.md`` for
+where each decision lands in the dataflow.
 
-Hardware numbers come in two flavours: the static spec-sheet defaults
-below (``DEFAULT_HW``), and *measured* :class:`HardwareProfile` entries
+Hardware numbers come in two flavours: published peaks keyed by the
+device's ``device_kind`` (``TPU_PEAKS``; :func:`device_profile` turns a
+live device into a profile, and an unknown kind is an error), with
+``DEFAULT_HW`` planning for a named chip when no device is live; and
+*measured* :class:`HardwareProfile` entries
 produced by :func:`calibrate`, which times the live execution backend
 (per-row throughput + launch latency from a two-point linear fit, link
 bandwidth from a staging transfer) so Eq. 10/11 decisions reflect the
@@ -35,17 +38,26 @@ the defaults.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
-# hardware constants (host numbers measured-order-of-magnitude; TPU per brief)
+# host numbers: measured order of magnitude
 HOST_FLOPS = 5e10          # ~50 GFLOP/s effective numpy single-core
 HOST_MEM_BW = 2e10         # bytes/s host memory effective
-TPU_FLOPS = 197e12         # bf16 peak per chip
-TPU_HBM_BW = 819e9
 HOST_TO_TPU_BW = 5e9       # PCIe/infeed-equivalent bytes/s
 TPU_LAUNCH_LATENCY = 5e-5  # dispatch overhead per call (s)
+
+# Published per-chip peaks, keyed by jax's ``device_kind``:
+# (bf16 FLOP/s, HBM bytes/s). Source: Google Cloud documentation, "TPU
+# v5e" (197 TFLOP/s bf16, 819 GB/s HBM). A kind missing here is an
+# error, not a borrowed default.
+TPU_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
+}
+# the chip the 'tpu' annotation plans for when no device is live
+PLANNING_TPU_KIND = "TPU v5 lite"
 
 
 @dataclass(frozen=True)
@@ -79,11 +91,28 @@ class HardwareProfile:
                 or self.flops_per_s / max(self.device_count, 1))
 
 
+HOST_HW = HardwareProfile("host", HOST_FLOPS, HOST_MEM_BW)
+
+
+def device_profile(name: str, device_kind: str) -> HardwareProfile:
+    """Spec-sheet profile of a device annotation served by a jax device
+    of ``device_kind``. An XLA:CPU device is the host; a TPU takes its
+    kind's published peaks, and a kind without any raises."""
+    if device_kind == "cpu":
+        return dataclasses.replace(HOST_HW, name=name)
+    if device_kind not in TPU_PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add "
+            f"them, with their source, to TPU_PEAKS (known: "
+            f"{sorted(TPU_PEAKS)})")
+    flops, bw = TPU_PEAKS[device_kind]
+    return HardwareProfile(name, flops, bw, link_bw=HOST_TO_TPU_BW,
+                           launch_latency_s=TPU_LAUNCH_LATENCY)
+
+
 DEFAULT_HW: Dict[str, HardwareProfile] = {
-    "host": HardwareProfile("host", HOST_FLOPS, HOST_MEM_BW),
-    "tpu": HardwareProfile("tpu", TPU_FLOPS, TPU_HBM_BW,
-                           link_bw=HOST_TO_TPU_BW,
-                           launch_latency_s=TPU_LAUNCH_LATENCY),
+    "host": HOST_HW,
+    "tpu": device_profile("tpu", PLANNING_TPU_KIND),
 }
 
 
@@ -426,24 +455,25 @@ class _CalibModel:
 # ---------------------------------------------------------------------------
 
 def profile_memo_fingerprint(parts) -> str:
-    """Host/backend/device-count identity of one calibration memo entry.
+    """Host/backend/device identity of one calibration memo entry.
 
     The key *is* the staleness guard: jax-flavoured backends embed the
-    jax version and live device count, host-only ones the cpu count, so
-    an upgrade or a different device topology simply misses the memo and
-    re-probes. Backends that never touch jax deliberately don't import
-    it here — spawned numpy workers stay jax-free."""
+    jax version, the platform, the ``device_kind`` and the live device
+    count, host-only ones the cpu count, so an upgrade, another chip or
+    a profile measured on the CPU simply misses the memo and re-probes.
+    Backends that never touch jax deliberately don't import it here —
+    spawned numpy workers stay jax-free."""
     import os
     import platform
     toks = [platform.node() or "host"]
     toks += [str(p) for p in parts if p is not None]
     if any("jax" in t for t in toks[1:]):
-        try:
-            import jax
-            toks.append(f"jax={jax.__version__}")
-            toks.append(f"jaxdev={jax.device_count()}")
-        except Exception:  # pragma: no cover - jax import failure
-            toks.append("jax=unavailable")
+        import jax
+        dev = jax.devices()[0]
+        toks.append(f"jax={jax.__version__}")
+        toks.append(f"platform={dev.platform}")
+        toks.append(f"kind={dev.device_kind}")
+        toks.append(f"jaxdev={jax.device_count()}")
     else:
         toks.append(f"cpus={os.cpu_count()}")
     return "|".join(toks)

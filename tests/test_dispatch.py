@@ -161,6 +161,31 @@ def test_fingerprint_embeds_topology():
     assert host != jax_fp
     assert (profile_memo_fingerprint(("jax-mesh", False, 2))
             != profile_memo_fingerprint(("jax-mesh", False, 4)))
+    # a profile measured on the CPU never serves a chip
+    assert "platform=cpu" in jax_fp and "kind=cpu" in jax_fp
+
+
+def test_dispatch_refuses_jax_workers_off_the_cpu(tmp_path, serve_zoo,
+                                                  table, monkeypatch):
+    """A chip belongs to one process: jax workers are refused unless JAX
+    is held to the CPU, while numpy workers are always allowed."""
+    sess = make_session(tmp_path, serve_zoo, table)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="one process"):
+        DispatchServer(session=sess, workers=1, worker_backend="jax")
+    srv = DispatchServer(session=sess, workers=1, worker_backend="numpy")
+    assert srv._worker_cfg.backend == "numpy"
+
+
+def test_dispatch_front_door_builds_numpy_session(tmp_path):
+    """Without a session the front door builds its own, and it never
+    holds a jax backend: it runs no inference."""
+    srv = DispatchServer(config=EngineConfig(model_store="decoupled"),
+                         root=tmp_path, workers=1, worker_backend="numpy",
+                         auto_calibrate=False)
+    assert srv.session.config.backend == "numpy"
+    assert {type(b).__name__ for b in srv.session.backends.values()} == {
+        "NumpyBackend"}
 
 
 def test_session_auto_calibration_writes_memo(tmp_path, serve_zoo, table):
