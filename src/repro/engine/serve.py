@@ -69,6 +69,7 @@ from repro.engine.config import EngineConfig
 from repro.engine.session import MorphingSession
 from repro.engine.sql import QueryStmt, parse
 from repro.engine.plan import _make_pred
+from repro.pipeline import spans
 from repro.pipeline.admission import (AdmissionPolicy, CircuitOpen,
                                       PRIORITIES, validate_priority)
 from repro.pipeline.backend import (ExecutionBackend, InferSpec,
@@ -161,6 +162,14 @@ class ServerStats:
     batch_rows_by_lane: Dict[str, int] = field(default_factory=dict)
     budget_shrinks: int = 0          # dynamic-budget shrink events
     budget_grows: int = 0            # dynamic-budget regrow events
+    # the lanes' spans and counters (repro.pipeline.spans), summed over
+    # lanes: seconds and calls per span name, and counter totals
+    span_seconds: Dict[str, float] = field(default_factory=dict)
+    span_calls: Dict[str, int] = field(default_factory=dict)
+    counts: Dict[str, int] = field(default_factory=dict)
+    p50_queue_wait_s: float = 0.0    # arrival -> popped by a lane worker
+    p95_queue_wait_s: float = 0.0
+    share_rows_held: int = 0         # gauge: rows the share cache holds
 
     @property
     def rows_per_second(self) -> float:
@@ -543,16 +552,17 @@ class MorphingServer:
         use_share = self.session.enable_share
 
         def step(payloads: List[Tuple[str, np.ndarray]]) -> List[np.ndarray]:
-            arrs = [np.asarray(p, np.float32) for _, p in payloads]
-            lens = [len(a) for a in arrs]
-            X = _stack(arrs, width=lane.in_dim or None)
-            n = len(X)
+            with spans.span("lane.stack"):
+                arrs = [np.asarray(p, np.float32) for _, p in payloads]
+                lens = [len(a) for a in arrs]
+                X = _stack(arrs, width=lane.in_dim or None)
             E = self._embed(lane, backend, share if use_share else None, X)
             offs = np.cumsum([0] + lens)
             outs: List[np.ndarray] = []
-            for (task, _), a, b in zip(payloads, offs[:-1], offs[1:]):
-                outs.append(lane.heads[task].run(E[a:b]) if b > a
-                            else np.zeros(0, np.float32))
+            with spans.span("lane.head"):
+                for (task, _), a, b in zip(payloads, offs[:-1], offs[1:]):
+                    outs.append(lane.heads[task].run(E[a:b]) if b > a
+                                else np.zeros(0, np.float32))
             return outs
         return step
 
@@ -588,33 +598,37 @@ class MorphingServer:
         # coalesced requests of this batch) compute once. The lane's
         # single worker serializes batches, so rows computed here are in
         # the cache before any later batch looks them up.
-        need_idx = np.flatnonzero(need)
-        uniq, first = np.unique(keys[need_idx], return_index=True)
-        comp_idx = need_idx[first]
+        with spans.span("lane.dedup"):
+            need_idx = np.flatnonzero(need)
+            uniq, first = np.unique(keys[need_idx], return_index=True)
+            comp_idx = need_idx[first]
         computed = np.asarray(
             backend.run_infer(lane.spec, {"x": X[comp_idx]})[lane.spec.out],
             np.float32)
-        E = (np.asarray(look.found, np.float32) if look.found is not None
-             else np.zeros((n, computed.shape[1]), np.float32))
-        fa = 0
-        if len(look.audit_idx):
-            exact = computed[np.searchsorted(uniq, keys[look.audit_idx])]
-            errs = np.linalg.norm(
-                E[look.audit_idx].astype(np.float64) - exact, axis=1)
-            order = np.argsort(look.approx_idx, kind="stable")
-            loc = order[np.searchsorted(look.approx_idx[order],
-                                        look.audit_idx)]
-            record = getattr(share, "record_audit", None)
-            if record is not None:
-                record(_SHARE_TABLE, lane.key, lane.key,
-                       look.approx_dist[loc], errs)
-            ann = getattr(share, "ann", None)
-            if ann is not None:
-                fa = int((errs > ann.cfg.error_bound).sum())
-        # computed[j] embeds uniq[j] (np.unique sorts): scatter back to
-        # every duplicate needed row in one searchsorted — audited rows
-        # get their exact recomputation, not the approximation
-        E[need_idx] = computed[np.searchsorted(uniq, keys[need_idx])]
+        with spans.span("lane.scatter"):
+            E = (np.asarray(look.found, np.float32)
+                 if look.found is not None
+                 else np.zeros((n, computed.shape[1]), np.float32))
+            fa = 0
+            if len(look.audit_idx):
+                exact = computed[np.searchsorted(uniq,
+                                                 keys[look.audit_idx])]
+                errs = np.linalg.norm(
+                    E[look.audit_idx].astype(np.float64) - exact, axis=1)
+                order = np.argsort(look.approx_idx, kind="stable")
+                loc = order[np.searchsorted(look.approx_idx[order],
+                                            look.audit_idx)]
+                record = getattr(share, "record_audit", None)
+                if record is not None:
+                    record(_SHARE_TABLE, lane.key, lane.key,
+                           look.approx_dist[loc], errs)
+                ann = getattr(share, "ann", None)
+                if ann is not None:
+                    fa = int((errs > ann.cfg.error_bound).sum())
+            # computed[j] embeds uniq[j] (np.unique sorts): scatter back
+            # to every duplicate needed row in one searchsorted — audited
+            # rows get their exact recomputation, not the approximation
+            E[need_idx] = computed[np.searchsorted(uniq, keys[need_idx])]
         share.insert_many(_SHARE_TABLE, lane.key, keys[comp_idx],
                           X[comp_idx], computed, version=lane.key)
         with lane.lock:
@@ -650,19 +664,25 @@ class MorphingServer:
         while the lane's breaker is open. The supervisor lives here: a
         tripped breaker past its cooldown is reset on the next submit
         (the lane "restarts" and the request is admitted)."""
-        validate_priority(priority)
-        task, col, table, preds = self._parse_predict(sql)
-        if task not in self.session.models:
-            if not self._running:
-                raise RuntimeError(
-                    "server not started: call start() or use "
-                    "'with server:'")
-            if sample is None:
-                raise RuntimeError(
-                    f"task {task} unresolved and no sample given")
-            self.resolve_task(task, *sample)
-        return self.submit_rows(task, self._rows_for(table, col, preds),
-                                priority=priority, deadline_ms=deadline_ms)
+        with spans.span("engine.submit") as sp:
+            validate_priority(priority)
+            with spans.span("engine.parse"):
+                task, col, table, preds = self._parse_predict(sql)
+            if task not in self.session.models:
+                if not self._running:
+                    raise RuntimeError(
+                        "server not started: call start() or use "
+                        "'with server:'")
+                if sample is None:
+                    raise RuntimeError(
+                        f"task {task} unresolved and no sample given")
+                self.resolve_task(task, *sample)
+            with spans.span("engine.filter"):
+                X = self._rows_for(table, col, preds)
+            req_id = self.submit_rows(task, X, priority=priority,
+                                      deadline_ms=deadline_ms)
+            sp.set_meta(req=req_id)
+        return req_id
 
     def submit_rows(self, task: str, X: np.ndarray, *,
                     priority: str = "batch",
@@ -737,6 +757,7 @@ class MorphingServer:
         st = ServerStats()
         st.devices = self.devices
         lat: List[float] = []
+        waits: List[float] = []
         lat_by_prio: Dict[str, List[float]] = {p: [] for p in PRIORITIES}
         coalesced: List[int] = []
         embed_seconds = 0.0
@@ -797,6 +818,9 @@ class MorphingServer:
                 st.infer_seconds += lane.spec.stats.infer_seconds
             lat.extend(lane_lat)
             coalesced.extend(lane_sizes)
+            waits.extend(lane.batcher.queue_wait_snapshot())
+            lane.batcher.sink.merge_into(st.span_seconds, st.span_calls,
+                                         st.counts)
         if embed_seconds:
             st.mesh_rows_per_s = st.embed_rows / embed_seconds
         if coalesced:
@@ -805,6 +829,10 @@ class MorphingServer:
             st.p50_latency_s = float(np.percentile(lat, 50))
             st.p95_latency_s = float(np.percentile(lat, 95))
             st.max_latency_s = float(np.max(lat))
+        if waits:
+            st.p50_queue_wait_s = float(np.percentile(waits, 50))
+            st.p95_queue_wait_s = float(np.percentile(waits, 95))
+        st.share_rows_held = self.session.share.rows_held
         for p, samples in lat_by_prio.items():
             if samples:
                 st.p50_latency_s_by_priority[p] = \
@@ -844,8 +872,10 @@ class MorphingServer:
         return {lane.key: lane.batcher.health() for lane in lanes}
 
     def reset_telemetry(self) -> None:
-        """Re-base every telemetry window: latency/batch-size deques,
-        share/dedup counters, and per-stage BatcherStats. Percentiles and
+        """Re-base every telemetry window: latency/queue-wait/batch-size
+        deques, share/dedup counters, per-stage BatcherStats and the
+        lanes' span sinks (the share cache's rows held is a gauge and
+        stays). Percentiles and
         rates from :meth:`stats` then describe only the traffic served
         after the reset (e.g. post-warmup). Pending requests still serve
         normally — only the counters restart."""

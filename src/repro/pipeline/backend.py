@@ -34,6 +34,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from repro.core.zoo import adapt_input_width
+from repro.pipeline import spans
 from repro.pipeline.batcher import BatcherStats, WindowBatcher
 
 
@@ -372,22 +373,33 @@ class JaxBackend(ExecutionBackend):
         n = len(X)
         if n == 0:
             return np.zeros(out_shape, np.float32)
-        Xp = adapt_input_width(np.asarray(X, np.float32), staged.in_dim)
-        d = staged.in_dim
         bucket = self._bucket_for(n)
-        if bucket == n:                       # aligned chunk: no pad copy
-            Xb = np.ascontiguousarray(Xp)
-        else:
-            Xb = np.zeros((bucket, d), np.float32)
-            Xb[:n] = Xp
-        key = (fn_key, bucket)
-        with self._lock:
-            new_shape = key not in staged.seen_shapes
+        with spans.span("backend.run_infer", bucket=bucket):
+            spans.count("backend.rows", n)
+            spans.count("backend.bucket_rows", bucket)
+            with spans.span("backend.pad"):
+                Xp = adapt_input_width(np.asarray(X, np.float32),
+                                       staged.in_dim)
+                if bucket == n:               # aligned chunk: no pad copy
+                    Xb = np.ascontiguousarray(Xp)
+                else:
+                    Xb = np.zeros((bucket, staged.in_dim), np.float32)
+                    Xb[:n] = Xp
+            key = (fn_key, bucket)
+            with self._lock:
+                new_shape = key not in staged.seen_shapes
+                if new_shape:
+                    staged.seen_shapes.add(key)
             if new_shape:
-                staged.seen_shapes.add(key)
-        if new_shape and self.on_compile is not None:
-            self.on_compile(staged.version, key)
-        out = np.asarray(fn(Xb, *staged.weights))
+                spans.count("backend.new_shapes", 1)
+                if self.on_compile is not None:
+                    self.on_compile(staged.version, key)
+            # a new shape's first call traces and compiles before it runs
+            with spans.span("backend.compile" if new_shape
+                            else "backend.call"):
+                y = fn(Xb, *staged.weights)
+            with spans.span("backend.fetch"):
+                out = np.asarray(y)
         return out[:n]
 
     def _features(self, spec: InferSpec, X: np.ndarray) -> np.ndarray:

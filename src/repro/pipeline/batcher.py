@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.pipeline import spans
 from repro.pipeline.admission import (AdmissionPolicy, CircuitOpen,
                                       LaneBreaker, Rejected, RequestError,
                                       PRIORITIES, validate_priority)
@@ -235,9 +236,15 @@ class ContinuousBatcher:
         # telemetry is windowed so a long-running service doesn't grow
         # without bound; per-request state is evicted by result()
         self.latencies: "deque[float]" = deque(maxlen=telemetry_window)
+        # each request's wait from arrival until the worker popped it
+        self.queue_waits: "deque[float]" = deque(maxlen=telemetry_window)
         self.batch_sizes: "deque[int]" = deque(maxlen=telemetry_window)
         self.lat_by_priority: Dict[str, "deque[float]"] = {
             p: deque(maxlen=telemetry_window) for p in PRIORITIES}
+        # the lane's spans and counters (repro.pipeline.spans): bound to
+        # the thread that collects and steps, swapped by reset_telemetry
+        self.sink = spans.Sink()
+        self._batch_seq = 0
 
     def _label(self) -> str:
         return f"lane {self.name!r}" if self.name else "batcher"
@@ -334,6 +341,7 @@ class ContinuousBatcher:
                 if self._queues[p] and self._credits[p] > 0:
                     self._credits[p] -= 1
                     req = self._queues[p].popleft()
+                    self.queue_waits.append(time.time() - req.arrival)
                     units = self.size_of(req.payload)
                     self._queued_units -= units
                     self._queued_units_by[p] -= units
@@ -351,6 +359,10 @@ class ContinuousBatcher:
             else self.batch_size
 
     def _collect(self, limit: Optional[int] = None) -> List[Request]:
+        with spans.bound(self.sink), spans.span("lane.collect"):
+            return self._collect_batch(limit)
+
+    def _collect_batch(self, limit: Optional[int]) -> List[Request]:
         # Block on the first request (bounded by idle_wait_s) so an empty
         # queue parks the thread in the OS wait instead of busy-spinning.
         with self._cv:
@@ -414,7 +426,21 @@ class ContinuousBatcher:
         is stored per request as a typed :class:`RequestError` (surfaced
         by ``result()``), returned raw (for ``run()``), and the lane
         worker survives to serve the next batch."""
-        outs, err, attempts = self._run_step(batch)
+        self._batch_seq += 1
+        with spans.bound(self.sink):
+            with spans.span("lane.step", batch=self._batch_seq,
+                            requests=len(batch),
+                            rows=sum(self.size_of(r.payload)
+                                     for r in batch)):
+                outs, err, attempts = self._run_step(batch)
+            with spans.span("lane.publish"):
+                return self._publish(batch, outs, err, attempts)
+
+    def _publish(self, batch: List[Request], outs: List[Any],
+                 err: Optional[Exception],
+                 attempts: int) -> Optional[Exception]:
+        """Store a step's results and latencies; update the breaker and
+        the dynamic budget."""
         now = time.time()
         if err is not None:
             wrapped = RequestError(
@@ -600,6 +626,7 @@ class ContinuousBatcher:
         phase don't mix pre- and post-warmup samples."""
         with self._cv:
             self.latencies.clear()
+            self.queue_waits.clear()
             self.batch_sizes.clear()
             for d in self.lat_by_priority.values():
                 d.clear()
@@ -609,6 +636,7 @@ class ContinuousBatcher:
             self.failed_batches = 0
             self.deadline_misses = 0
             self.deadlines_admitted = 0
+            self.sink = spans.Sink()
 
     def telemetry(self) -> Tuple[List[float], List[int]]:
         """Consistent snapshot of (latencies, batch sizes) — the live
@@ -616,6 +644,11 @@ class ContinuousBatcher:
         iterate them directly."""
         with self._cv:
             return list(self.latencies), list(self.batch_sizes)
+
+    def queue_wait_snapshot(self) -> List[float]:
+        """Consistent snapshot of the windowed queue waits (seconds)."""
+        with self._cv:
+            return list(self.queue_waits)
 
     @property
     def pending(self) -> int:

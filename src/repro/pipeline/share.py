@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import hashlib
 import threading
-import time
 import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -29,6 +28,7 @@ from typing import (Callable, Dict, List, Optional, Protocol, Sequence,
 
 import numpy as np
 
+from repro.pipeline import spans
 from repro.storage import mvec
 
 
@@ -125,8 +125,10 @@ class _RowBlock:
         if self.used == 0:
             return np.zeros(len(q), np.int64), np.zeros(len(q), bool)
         if self._sorted is None:
-            self._order = np.argsort(self.fps[:self.used])
-            self._sorted = self.fps[:self.used][self._order]
+            with spans.span("share.resort"):
+                spans.count("share.resort_rows", self.used)
+                self._order = np.argsort(self.fps[:self.used])
+                self._sorted = self.fps[:self.used][self._order]
         pos = np.searchsorted(self._sorted, q)
         pos[pos == self.used] = 0            # clamp; mask rejects below
         found = self._sorted[pos] == q
@@ -142,13 +144,15 @@ class _RowBlock:
             return 0
         need = self.used + len(sel)
         if need > len(self.E):
-            cap = max(need, 2 * len(self.E))
-            grown = np.empty((cap, self.E.shape[1]), self.E.dtype)
-            grown[:self.used] = self.E[:self.used]
-            self.E = grown
-            gfps = np.empty(cap, np.uint64)
-            gfps[:self.used] = self.fps[:self.used]
-            self.fps = gfps
+            with spans.span("share.grow"):
+                spans.count("share.grow_bytes", self.nbytes)  # copied
+                cap = max(need, 2 * len(self.E))
+                grown = np.empty((cap, self.E.shape[1]), self.E.dtype)
+                grown[:self.used] = self.E[:self.used]
+                self.E = grown
+                gfps = np.empty(cap, np.uint64)
+                gfps[:self.used] = self.fps[:self.used]
+                self.fps = gfps
         before = self.nbytes
         self.E[self.used:need] = rows[sel]
         self.fps[self.used:need] = fps[sel]
@@ -176,8 +180,6 @@ class _RowBlock:
 class ShareStats:
     hits: int = 0
     misses: int = 0
-    embed_seconds: float = 0.0
-    bytes_stored: int = 0
 
 
 def _no_idx() -> np.ndarray:
@@ -273,16 +275,12 @@ class VectorShareCache:
                 self.stats.hits += 1
                 self._put(key, np.asarray(vec))
             return np.asarray(vec)
-        t0 = time.time()
         vec = np.asarray(embed_fn(data))
-        dt = time.time() - t0
         with self._lock:
             self.stats.misses += 1
-            self.stats.embed_seconds += dt
             self._put(key, vec)
         if self.root:
             (self.root / f"{key}.mvec").write_bytes(mvec.encode(vec))
-            self.stats.bytes_stored += vec.nbytes
         return vec
 
     def _put(self, key: str, vec: np.ndarray) -> None:
@@ -315,20 +313,8 @@ class VectorShareCache:
         Hit/miss stats are counted per *row* — the serving analogue of
         the chunk-level counts ``get_or_embed`` keeps.
         """
-        keys = fingerprint_rows(np.asarray(rows))
-        n = len(keys)
-        with self._lock:
-            block = self._rows.get(self._blockkey(table, column, version))
-            if block is None or block.used == 0:
-                self.stats.misses += n
-                return keys, None, np.ones(n, bool)
-            self._rows.move_to_end(self._blockkey(table, column, version))
-            idx, hit = block.lookup(keys)
-            miss = ~hit
-            found = block.E[idx]         # miss rows: clamped idx, garbage
-            self.stats.hits += int(hit.sum())
-            self.stats.misses += int(miss.sum())
-        return keys, found, miss
+        look = self.lookup_many(table, column, rows, version)
+        return look.keys, look.found, look.miss
 
     def put_many(self, table: str, column: str, keys: np.ndarray,
                  rows: np.ndarray, version: str = "v1") -> None:
@@ -340,7 +326,8 @@ class VectorShareCache:
         if len(keys) != len(rows):
             raise ValueError(f"{len(keys)} keys for {len(rows)} rows")
         bk = self._blockkey(table, column, version)
-        with self._lock:
+        with spans.span("share.insert"), self._lock:
+            spans.count("share.insert_rows", len(keys))
             block = self._rows.get(bk)
             if block is None:
                 block = _RowBlock(rows.shape[1], rows.dtype,
@@ -350,13 +337,18 @@ class VectorShareCache:
             self._rows_used += block.put(keys, rows)
             while (self._rows_used + self._used > self.capacity
                    and len(self._rows) > 1):
-                _, old = self._rows.popitem(last=False)
-                self._rows_used -= old.nbytes
+                with spans.span("share.evict"):
+                    _, old = self._rows.popitem(last=False)
+                    self._rows_used -= old.nbytes
+                    spans.count("share.evicted_rows", old.used)
             # a lone block must not grow unbounded (it would also starve
             # the chunk tier forever): shed its oldest rows until the
             # combined usage fits
             while self._rows_used + self._used > self.capacity:
-                freed = block.drop_oldest()
+                with spans.span("share.evict"):
+                    held = block.used
+                    freed = block.drop_oldest()
+                    spans.count("share.evicted_rows", held - block.used)
                 if freed == 0:
                     break
                 self._rows_used -= freed
@@ -368,23 +360,26 @@ class VectorShareCache:
         """:class:`CacheTier` lookup: exact fingerprint equality. With
         precomputed ``keys`` the rows are not re-hashed (the chain path
         fingerprints once for all tiers)."""
-        if keys is None:
-            k, found, miss = self.get_many(table, column, rows, version)
-            return TierLookup(k, found, miss)
-        keys = np.asarray(keys, np.uint64)
-        n = len(keys)
-        bk = self._blockkey(table, column, version)
-        with self._lock:
-            block = self._rows.get(bk)
-            if block is None or block.used == 0:
-                self.stats.misses += n
-                return TierLookup(keys, None, np.ones(n, bool))
-            self._rows.move_to_end(bk)
-            idx, hit = block.lookup(keys)
-            miss = ~hit
-            found = block.E[idx]
-            self.stats.hits += int(hit.sum())
-            self.stats.misses += int(miss.sum())
+        with spans.span("share.lookup"):
+            if keys is None:
+                with spans.span("share.fingerprint"):
+                    keys = fingerprint_rows(np.asarray(rows))
+            else:
+                keys = np.asarray(keys, np.uint64)
+            n = len(keys)
+            spans.count("share.lookup_rows", n)
+            bk = self._blockkey(table, column, version)
+            with self._lock:
+                block = self._rows.get(bk)
+                if block is None or block.used == 0:
+                    self.stats.misses += n
+                    return TierLookup(keys, None, np.ones(n, bool))
+                self._rows.move_to_end(bk)
+                idx, hit = block.lookup(keys)
+                miss = ~hit
+                found = block.E[idx]     # miss rows: clamped idx, garbage
+                self.stats.hits += int(hit.sum())
+                self.stats.misses += int(miss.sum())
         return TierLookup(keys, found, miss)
 
     def insert_many(self, table: str, column: str, keys: np.ndarray,
@@ -422,6 +417,12 @@ class VectorShareCache:
     @staticmethod
     def _blockkey(table: str, column: str, version: str) -> str:
         return f"{table}.{column}.{version}"
+
+    @property
+    def rows_held(self) -> int:
+        """Rows the row tier holds now, over every block (a gauge)."""
+        with self._lock:
+            return sum(b.used for b in self._rows.values())
 
     @property
     def hit_rate(self) -> float:
