@@ -99,22 +99,45 @@ def fingerprint_rows(arr: np.ndarray) -> np.ndarray:
     return h
 
 
+def _search(keys: np.ndarray, q: np.ndarray
+            ) -> Tuple[np.ndarray, np.ndarray]:
+    """Insertion points of ``q`` in the sorted ``keys``, and which of
+    ``q`` are there."""
+    pos = np.searchsorted(keys, q)
+    if len(keys) == 0:
+        return pos, np.zeros(len(q), bool)
+    return pos, keys.take(pos, mode="clip") == q
+
+
 class _RowBlock:
     """Row-granular store for one (table, column, version) key space:
     embeddings live in one contiguous matrix keyed by a parallel
     fingerprint vector, so a batched lookup is one ``searchsorted`` over
     the sorted fingerprints plus one fancy-index gather — no per-row
-    Python. The sort order is rebuilt lazily after inserts (inserts are
-    the cold path; lookups are the serving hot path)."""
+    Python. The sorted index (``_sorted`` fingerprints, ``_order`` their
+    rows of ``E``) is never rebuilt: an insert keeps its new rows as a
+    pending sorted run, with the insertion points its dedup search found,
+    and the next lookup merges that run into the index in one linear
+    pass; a shed filters the index to the rows kept."""
 
-    __slots__ = ("E", "fps", "used", "_sorted", "_order")
+    __slots__ = ("E", "fps", "used", "_sorted", "_order", "_run", "_bufs",
+                 "_spare")
 
     def __init__(self, width: int, dtype, cap: int = 256):
         self.E = np.empty((cap, width), dtype)
         self.fps = np.empty(cap, np.uint64)
         self.used = 0
-        self._sorted: Optional[np.ndarray] = None
-        self._order: Optional[np.ndarray] = None
+        # rows not in the index yet: (fingerprints ascending, their rows
+        # of E, their insertion points in _sorted), or None
+        self._run: Optional[Tuple[np.ndarray, np.ndarray,
+                                  np.ndarray]] = None
+        # _sorted and _order are the first rows of _bufs; a merge writes
+        # into _spare, whose pages are mapped already, and swaps the two
+        self._set_index(np.zeros(0, np.uint64), np.zeros(0, np.int64))
+
+    def _set_index(self, keys: np.ndarray, rows: np.ndarray) -> None:
+        self._bufs = self._sorted, self._order = keys, rows
+        self._spare = (np.zeros(0, np.uint64), np.zeros(0, np.int64))
 
     @property
     def nbytes(self) -> int:
@@ -124,20 +147,40 @@ class _RowBlock:
         """(row indices into E, found mask) for fingerprints ``q``."""
         if self.used == 0:
             return np.zeros(len(q), np.int64), np.zeros(len(q), bool)
-        if self._sorted is None:
+        if self._run is not None:
             with spans.span("share.resort"):
-                spans.count("share.resort_rows", self.used)
-                self._order = np.argsort(self.fps[:self.used])
-                self._sorted = self.fps[:self.used][self._order]
-        pos = np.searchsorted(self._sorted, q)
-        pos[pos == self.used] = 0            # clamp; mask rejects below
-        found = self._sorted[pos] == q
-        return self._order[pos], found
+                self._merge_run()
+        pos, found = _search(self._sorted, q)
+        # a miss past the last key clips to it; ``found`` rejects it
+        return self._order.take(pos, mode="clip"), found
+
+    def _merge_run(self) -> None:
+        """Merge the pending run into the index: each old entry moves up
+        by the number of run keys below it, in one masked copy."""
+        keys, rows, pos = self._run
+        spans.count("share.resort_rows", len(keys))
+        m = self.used
+        spare = self._spare
+        if len(spare[0]) < m:                # sized like E, which doubles
+            spare = (np.empty(len(self.E), np.uint64),
+                     np.empty(len(self.E), np.int64))
+        at = pos + np.arange(len(keys))
+        old = np.ones(m, bool)
+        old[at] = False
+        for buf, new, held in zip(spare, (keys, rows),
+                                  (self._sorted, self._order)):
+            buf[at] = new
+            buf[:m][old] = held
+        self._spare, self._bufs = self._bufs, spare
+        self._sorted, self._order = spare[0][:m], spare[1][:m]
+        self._run = None
 
     def put(self, fps: np.ndarray, rows: np.ndarray) -> int:
         """Insert rows whose fingerprints aren't present; returns bytes
         added. Duplicates (in-call or vs stored) insert once."""
-        _, present = self.lookup(fps)
+        pos, present = _search(self._sorted, fps)
+        if self._run is not None:
+            present |= _search(self._run[0], fps)[1]
         uniq, first = np.unique(fps[~present], return_index=True)
         sel = np.flatnonzero(~present)[first]
         if len(sel) == 0:
@@ -156,14 +199,19 @@ class _RowBlock:
         before = self.nbytes
         self.E[self.used:need] = rows[sel]
         self.fps[self.used:need] = fps[sel]
+        run = (uniq, np.arange(self.used, need), pos[sel])
+        if self._run is not None:            # two runs: merge, both small
+            o = np.argsort(np.concatenate([self._run[0], uniq]))
+            run = tuple(np.concatenate(ab)[o] for ab in zip(self._run, run))
+        self._run = run
         self.used = need
-        self._sorted = self._order = None    # re-sort lazily
         return self.nbytes - before
 
     def drop_oldest(self, keep_frac: float = 0.5) -> int:
         """Evict the oldest (insertion-order) rows, keeping the newest
         ``keep_frac``; the buffers are reallocated so freed memory is
-        actually returned. Returns bytes freed."""
+        actually returned, and the index keeps the rows kept, renumbered.
+        Returns bytes freed."""
         keep = max(int(self.used * keep_frac), 1)
         start = self.used - keep
         if start <= 0:
@@ -172,7 +220,13 @@ class _RowBlock:
         self.E = self.E[start:self.used].copy()
         self.fps = self.fps[start:self.used].copy()
         self.used = keep
-        self._sorted = self._order = None
+        live = self._order >= start
+        self._set_index(self._sorted[live], self._order[live] - start)
+        if self._run is not None:
+            keys, rows, _ = self._run
+            live = rows >= start
+            self._run = (keys[live], rows[live] - start,
+                         np.searchsorted(self._sorted, keys[live]))
         return before - self.nbytes
 
 

@@ -233,6 +233,85 @@ def test_share_cache_row_blocks_evict_lru():
     assert not miss3.any()                    # newest survives
 
 
+# case: (initial capacity, op weights put / lookup / drop, steps spent
+# on an empty block first)
+ROW_BLOCK_CASES = {
+    "growth": (1, (3, 1, 0), 0),
+    "shed": (16, (2, 1, 1), 0),
+    "pending_run_across_puts": (16, (4, 1, 1), 0),
+    "empty_block": (8, (1, 1, 1), 12),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_BLOCK_CASES))
+def test_row_block_index_matches_a_dict(case):
+    """A ``_RowBlock`` driven through a seeded interleaving of puts
+    (fresh, repeated and in-call duplicate fingerprints), lookups,
+    buffer growth and sheds agrees with a dict after every step, and
+    after a lookup its index is the sorted fingerprints held."""
+    from repro.pipeline.share import _RowBlock
+    cap, weights, empty_steps = ROW_BLOCK_CASES[case]
+    rng = np.random.default_rng(sorted(ROW_BLOCK_CASES).index(case))
+    width = 3
+    # few keys, so puts repeat them; the ends of uint64 too
+    universe = np.unique(np.concatenate([
+        rng.integers(0, 2**63, 60, dtype=np.uint64),
+        np.array([0, 2**64 - 1], np.uint64)]))
+    block = _RowBlock(width, np.float32, cap=cap)
+    ref = {}                                  # fingerprint -> row stored
+    order = []                                # fingerprints, oldest first
+    puts_onto_a_run = 0
+    for step in range(120):
+        op = rng.choice(["put", "lookup", "drop"],
+                        p=np.array(weights) / sum(weights))
+        if op == "put":
+            n = 0 if step < empty_steps else int(rng.integers(0, 24))
+            fps = rng.choice(universe, n)     # in-call duplicates too
+            rows = rng.standard_normal((n, width)).astype(np.float32)
+            puts_onto_a_run += block._run is not None and n > 0
+            fresh = {}
+            for fp, row in zip(fps.tolist(), rows):
+                if fp not in ref:             # first one in wins
+                    fresh.setdefault(fp, row)
+            ref.update(fresh)
+            order += sorted(fresh)            # a put stores them sorted
+            assert block.put(fps, rows) == len(fresh) * (width * 4 + 8)
+        elif op == "drop":
+            freed = block.drop_oldest(float(rng.uniform(0.1, 0.9)))
+            gone = len(order) - block.used
+            for fp in order[:gone]:
+                del ref[fp]
+            assert freed == gone * (width * 4 + 8)
+            order = order[gone:]
+        else:
+            q = np.concatenate([universe, rng.choice(universe, 16)])
+            rng.shuffle(q)
+            idx, found = block.lookup(q)
+            want = np.array([fp in ref for fp in q.tolist()])
+            np.testing.assert_array_equal(found, want)
+            if want.any():
+                np.testing.assert_array_equal(
+                    block.E[idx[found]],
+                    np.stack([ref[fp] for fp in q[found].tolist()]))
+            if block.used:
+                assert block._run is None
+            np.testing.assert_array_equal(
+                block._sorted, np.sort(block.fps[:block.used]))
+            np.testing.assert_array_equal(block.fps[block._order],
+                                          block._sorted)
+        assert block.used == len(order) <= len(block.E)
+        np.testing.assert_array_equal(block.fps[:block.used],
+                                      np.array(order, np.uint64))
+        if order:
+            np.testing.assert_array_equal(
+                block.E[:block.used], np.stack([ref[fp] for fp in order]))
+    assert len(block.E) > cap or case == "empty_block"
+    if case == "pending_run_across_puts":
+        assert puts_onto_a_run >= 5
+    if case in ("shed", "pending_run_across_puts", "empty_block"):
+        assert 0 < len(order) < len(universe)  # the sheds left keys out
+
+
 def test_pipeline_chunked_matches_single_shot():
     rng = np.random.default_rng(0)
     n = 500

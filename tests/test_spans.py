@@ -134,10 +134,10 @@ def test_one_resort_per_batch_after_an_insert(tmp_path, zoo, sample):
                     st.counts.get("share.resort_rows", 0),
                     st.counts.get("share.insert_rows", 0))
         assert batch(0, 200) == (0, 0, 200)      # empty cache: no sort
-        assert batch(0, 200) == (1, 200, 0)      # after an insert
+        assert batch(0, 200) == (1, 200, 0)      # merges the insert
         assert batch(0, 200) == (0, 0, 0)        # all hits, sorted
         assert batch(100, 300) == (0, 0, 100)    # sorted still; inserts
-        assert batch(0, 300) == (1, 300, 0)
+        assert batch(0, 300) == (1, 100, 0)      # merges the 100 only
     finally:
         server.stop()
 
@@ -146,12 +146,23 @@ def test_evicted_rows_match_drop_oldest(tmp_path, zoo, sample):
     # 12 float32 features and an 8-byte fingerprint a row: 56 B; the
     # cache holds 357 rows, so each 300-row insert after the first takes
     # it to 600 rows and it sheds the oldest 300
-    server = serve(tmp_path, zoo, sample, n=1200,
+    server = serve(tmp_path, zoo, sample, n=1300,
                    share_capacity_bytes=20_000)
     try:
         for a in range(0, 1200, 300):
             server.predict(ids(a, a + 300), timeout=60.0)
         st = server.stats()
+        # 200 hits merge the last insert; 100 new rows take the cache to
+        # 400 and it sheds 200, keeping 100 rows the index held and the
+        # 100 new
+        server.reset_telemetry()
+        first = server.predict(ids(1000, 1300), timeout=60.0)
+        shed = server.stats()
+        # the next lookup merges the 100 new rows; it re-sorts none of
+        # the 100 the index kept through the shed
+        server.reset_telemetry()
+        again = server.predict(ids(1200, 1300), timeout=60.0)
+        after = server.stats()
     finally:
         server.stop()
     c = st.counts
@@ -163,6 +174,15 @@ def test_evicted_rows_match_drop_oldest(tmp_path, zoo, sample):
     # insert grows them again, copying the 300 rows held
     assert st.span_calls["share.grow"] == 3
     assert c["share.grow_bytes"] == 3 * 300 * 56
+    assert (shed.span_calls["share.resort"],
+            shed.counts["share.resort_rows"]) == (1, 300)
+    assert shed.counts["share.evicted_rows"] == 200
+    assert shed.share_rows_held == 200
+    assert (after.span_calls["share.resort"],
+            after.counts["share.resort_rows"]) == (1, 100)
+    assert after.share_hits == 100 and after.share_misses == 0
+    assert after.share_rows_held == 200
+    np.testing.assert_array_equal(again.scores, first.scores[200:])
 
 
 def test_queue_waits_are_no_longer_than_latencies(tmp_path, zoo, sample):
