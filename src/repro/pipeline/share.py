@@ -99,26 +99,42 @@ def fingerprint_rows(arr: np.ndarray) -> np.ndarray:
     return h
 
 
-def _search(keys: np.ndarray, q: np.ndarray
+def _search(keys: np.ndarray, q: np.ndarray,
+            vals: Optional[np.ndarray] = None
             ) -> Tuple[np.ndarray, np.ndarray]:
-    """Insertion points of ``q`` in the sorted ``keys``, and which of
-    ``q`` are there."""
-    pos = np.searchsorted(keys, q)
+    """For each of ``q``: its insertion point in the sorted ``keys`` or,
+    given ``vals`` (parallel to ``keys``), the entry of ``vals`` there,
+    clipped to the last; and whether it is in ``keys``. The probes are
+    searched in ascending order, so each descent starts from the last
+    one's bound and neighbouring descents share cache lines; both reads
+    at the insertion points follow that order, and only the answers are
+    scattered back to the order of ``q``."""
+    o = np.argsort(q)
+    sq = q[o]
+    pos = np.searchsorted(keys, sq)
     if len(keys) == 0:
-        return pos, np.zeros(len(q), bool)
-    return pos, keys.take(pos, mode="clip") == q
+        hit = np.zeros(len(q), bool)
+    else:
+        hit = keys.take(pos, mode="clip") == sq
+        if vals is not None:
+            pos = vals.take(pos, mode="clip")
+    at, found = np.empty_like(pos), np.empty_like(hit)
+    at[o] = pos
+    found[o] = hit
+    return at, found
 
 
 class _RowBlock:
     """Row-granular store for one (table, column, version) key space:
     embeddings live in one contiguous matrix keyed by a parallel
-    fingerprint vector, so a batched lookup is one ``searchsorted`` over
-    the sorted fingerprints plus one fancy-index gather — no per-row
-    Python. The sorted index (``_sorted`` fingerprints, ``_order`` their
-    rows of ``E``) is never rebuilt: an insert keeps its new rows as a
-    pending sorted run, with the insertion points its dedup search found,
-    and the next lookup merges that run into the index in one linear
-    pass; a shed filters the index to the rows kept."""
+    fingerprint vector, so a batched lookup is one ``searchsorted`` of
+    the sorted probes over the sorted fingerprints plus one fancy-index
+    gather — no per-row Python. The sorted index (``_sorted``
+    fingerprints, ``_order`` their rows of ``E``) is never rebuilt: an
+    insert keeps its new rows as a pending sorted run, with the insertion
+    points its dedup search found, and the next lookup merges that run
+    into the index in one linear pass; a shed filters the index to the
+    rows kept."""
 
     __slots__ = ("E", "fps", "used", "_sorted", "_order", "_run", "_bufs",
                  "_spare")
@@ -150,9 +166,9 @@ class _RowBlock:
         if self._run is not None:
             with spans.span("share.resort"):
                 self._merge_run()
-        pos, found = _search(self._sorted, q)
-        # a miss past the last key clips to it; ``found`` rejects it
-        return self._order.take(pos, mode="clip"), found
+        with spans.span("share.search"):
+            # a miss past the last key clips to it; ``found`` rejects it
+            return _search(self._sorted, q, self._order)
 
     def _merge_run(self) -> None:
         """Merge the pending run into the index: each old entry moves up

@@ -30,7 +30,7 @@ SPAN_NAMES: Tuple[str, ...] = (
     # children of lane.step
     "lane.stack", "lane.dedup", "lane.scatter", "lane.head",
     # the share cache
-    "share.lookup", "share.fingerprint", "share.resort",
+    "share.lookup", "share.fingerprint", "share.search", "share.resort",
     "share.insert", "share.grow", "share.evict",
     # the trunk call
     "backend.run_infer", "backend.pad", "backend.call", "backend.fetch",

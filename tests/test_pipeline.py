@@ -233,13 +233,55 @@ def test_share_cache_row_blocks_evict_lru():
     assert not miss3.any()                    # newest survives
 
 
+_KEYS = [3, 10, 10**6, 2**40, 2**63 + 5]        # sorted, distinct
+_MANY = np.unique(np.random.default_rng(7).integers(
+    0, 2**64 - 1, 1024, np.uint64, endpoint=True))
+# case: (sorted keys, probes)
+SEARCH_CASES = {
+    "unsorted_with_duplicates": (_KEYS, [2**40, 7, 3, 2**40, 10, 7,
+                                         2**63 + 5, 3, 11, 3]),
+    "outside_the_keys": (_KEYS, [2, 2**63 + 6, 0, 2**64 - 1, 1, 2**63]),
+    "ends_of_uint64": ([0, 5, 2**64 - 1], [2**64 - 1, 0, 1, 2**64 - 2, 0,
+                                           2**64 - 1]),
+    "empty_index": ([], [5, 0, 2**64 - 1, 5]),
+    "empty_probes": (_KEYS, []),
+    "single_probe": (_KEYS, [10**6]),
+    "all_hits": (_KEYS, _KEYS[::-1] + _KEYS[1::2]),
+    "all_misses": (_KEYS, [k + 1 for k in _KEYS] + [0, 4, 9]),
+    "many_random": (_MANY[::2], np.random.default_rng(8).choice(_MANY, 4096)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEARCH_CASES))
+def test_search_in_key_order_matches_searchsorted(case):
+    """``_search`` sorts its probes before the search and scatters the
+    answers back: each answer equals a plain ``searchsorted`` in request
+    order and an equality test at the insertion point, and with
+    ``vals`` the entry of ``vals`` there, clipped to the last."""
+    from repro.pipeline.share import _search
+    keys, q = (np.array(a, np.uint64) for a in SEARCH_CASES[case])
+    pos = np.searchsorted(keys, q)
+    want = (keys.take(pos, mode="clip") == q if len(keys)
+            else np.zeros(len(q), bool))
+    at, found = _search(keys, q)
+    np.testing.assert_array_equal(at, pos)
+    np.testing.assert_array_equal(found, want)
+    assert (at.dtype, found.dtype) == (pos.dtype, np.dtype(bool))
+    if len(keys):
+        vals = np.arange(len(keys), dtype=np.int64)[::-1] * 7
+        rows, found = _search(keys, q, vals)
+        np.testing.assert_array_equal(rows, vals.take(pos, mode="clip"))
+        np.testing.assert_array_equal(found, want)
+
+
 # case: (initial capacity, op weights put / lookup / drop, steps spent
-# on an empty block first)
+# on an empty block first, keys a lookup also probes outside the universe)
 ROW_BLOCK_CASES = {
-    "growth": (1, (3, 1, 0), 0),
-    "shed": (16, (2, 1, 1), 0),
-    "pending_run_across_puts": (16, (4, 1, 1), 0),
-    "empty_block": (8, (1, 1, 1), 12),
+    "growth": (1, (3, 1, 0), 0, 0),
+    "shed": (16, (2, 1, 1), 0, 0),
+    "pending_run_across_puts": (16, (4, 1, 1), 0, 0),
+    "empty_block": (8, (1, 1, 1), 12, 0),
+    "wide_lookups": (16, (2, 2, 1), 0, 512),
 }
 
 
@@ -250,7 +292,7 @@ def test_row_block_index_matches_a_dict(case):
     buffer growth and sheds agrees with a dict after every step, and
     after a lookup its index is the sorted fingerprints held."""
     from repro.pipeline.share import _RowBlock
-    cap, weights, empty_steps = ROW_BLOCK_CASES[case]
+    cap, weights, empty_steps, outside = ROW_BLOCK_CASES[case]
     rng = np.random.default_rng(sorted(ROW_BLOCK_CASES).index(case))
     width = 3
     # few keys, so puts repeat them; the ends of uint64 too
@@ -285,6 +327,10 @@ def test_row_block_index_matches_a_dict(case):
             order = order[gone:]
         else:
             q = np.concatenate([universe, rng.choice(universe, 16)])
+            if outside:                       # misses between held keys
+                q = np.concatenate([q, rng.integers(
+                    0, 2**64 - 1, outside, np.uint64, endpoint=True)])
+                assert len(q) > 8 * len(universe) > 8 * block.used
             rng.shuffle(q)
             idx, found = block.lookup(q)
             want = np.array([fp in ref for fp in q.tolist()])
@@ -308,7 +354,8 @@ def test_row_block_index_matches_a_dict(case):
     assert len(block.E) > cap or case == "empty_block"
     if case == "pending_run_across_puts":
         assert puts_onto_a_run >= 5
-    if case in ("shed", "pending_run_across_puts", "empty_block"):
+    if case in ("shed", "pending_run_across_puts", "empty_block",
+                "wide_lookups"):
         assert 0 < len(order) < len(universe)  # the sheds left keys out
 
 

@@ -23,7 +23,7 @@ CHILDREN = {
     "lane.step": ("lane.stack", "share.lookup", "lane.dedup",
                   "backend.run_infer", "lane.scatter", "share.insert",
                   "lane.head"),
-    "share.lookup": ("share.fingerprint", "share.resort"),
+    "share.lookup": ("share.fingerprint", "share.search", "share.resort"),
     "share.insert": ("share.grow", "share.evict"),
     "backend.run_infer": ("backend.pad", "backend.call", "backend.fetch",
                           "backend.compile"),
@@ -142,6 +142,24 @@ def test_one_resort_per_batch_after_an_insert(tmp_path, zoo, sample):
         server.stop()
 
 
+def test_one_search_per_lookup_of_a_filled_block(tmp_path, zoo, sample):
+    server = serve(tmp_path, zoo, sample)
+    try:
+        def batch(a, b):
+            server.reset_telemetry()
+            server.predict(ids(a, b), timeout=60.0)
+            st = server.stats()
+            return (st.span_calls.get("share.lookup", 0),
+                    st.span_calls.get("share.search", 0),
+                    st.counts.get("share.lookup_rows", 0))
+        assert batch(0, 200) == (1, 0, 200)      # empty cache: no search
+        assert batch(0, 200) == (1, 1, 200)      # all hits
+        assert batch(100, 300) == (1, 1, 200)    # half miss
+        assert batch(400, 600) == (1, 1, 200)    # all miss
+    finally:
+        server.stop()
+
+
 def test_evicted_rows_match_drop_oldest(tmp_path, zoo, sample):
     # 12 float32 features and an 8-byte fingerprint a row: 56 B; the
     # cache holds 357 rows, so each 300-row insert after the first takes
@@ -251,9 +269,9 @@ def test_profiler_trace_holds_the_program_spans(tmp_path, zoo, sample):
     trace = ps.load(str(tmp_path / "trace"))
     for name in ("engine.submit", "engine.parse", "engine.filter",
                  "lane.step", "lane.publish", "share.lookup",
-                 "share.fingerprint", "share.resort", "share.insert",
-                 "lane.dedup", "backend.run_infer", "backend.pad",
-                 "backend.fetch", "lane.head"):
+                 "share.fingerprint", "share.search", "share.resort",
+                 "share.insert", "lane.dedup", "backend.run_infer",
+                 "backend.pad", "backend.fetch", "lane.head"):
         assert trace.spans[name], name
     assert len(trace.spans["lane.step"]) == 2
     (a, b), = trace.spans["backend.run_infer"]
