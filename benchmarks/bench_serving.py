@@ -1,12 +1,14 @@
 """Serving-path ablation: per-request query execution vs the
 continuous-batching ``MorphingServer`` on the same concurrent
-``PREDICT ... USING TASK`` workload; the share-aware trunk-lane server
-vs per-task full-predict lanes on an *overlapping-request* workload
-(where warm rows should cost head-only work); the fine-tune
-*delta-fleet* workload (K fine-tunes of one base serve through a single
-shared embed lane at base + K·delta loaded bytes, vs K per-task lanes
-re-running the trunk); plus the partial-load resolution story
-(loaded-vs-stored bytes on the decoupled store).
+``PREDICT ... USING TASK`` workload; the server with the share cache on
+vs the same server over a session with sharing off
+(``enable_share=False``: every row runs the trunk) on an
+*overlapping-request* workload (where warm rows should cost head-only
+work); the fine-tune *delta-fleet* workload (K fine-tunes of one base
+serve through a single shared embed lane at base + K·delta loaded
+bytes, vs the same lane re-running the trunk with sharing off); plus
+the partial-load resolution story (loaded-vs-stored bytes on the
+decoupled store).
 
 Run directly for machine-readable output::
 
@@ -44,16 +46,16 @@ CONCURRENCY = 8
 # startup and compile overheads dominate tiny request counts)
 MIN_REQUESTS_FOR_ASSERT = 32
 TARGET_SPEEDUP = 2.0
-# share-aware trunk lanes vs the per-task full-predict lanes on the
-# overlapping workload: warm rows approach head-only cost
+# the share cache on vs off on the overlapping workload: warm rows
+# approach head-only cost
 TARGET_SHARE_SPEEDUP = 1.5
 # the overlap ablation runs a wider trunk so the embed stage carries the
 # cost the share cache is supposed to remove
 OVERLAP_TRUNK_WIDTH = 160
 # fine-tune fleet: K delta variants of one base, served through one
-# shared embed lane; the ablation gives each task its own full-predict
-# lane (K trunk recomputations). Loaded bytes must stay near the
-# marginal cost base + K·delta, not K·full.
+# shared embed lane; the ablation turns sharing off, so every row runs
+# the trunk again. Loaded bytes must stay near the marginal cost
+# base + K·delta, not K·full.
 DELTA_FLEET_K = 4
 TARGET_DELTA_SPEEDUP = 1.5
 DELTA_BYTES_FACTOR = 1.5
@@ -89,10 +91,10 @@ def _statements(n_requests: int):
             f"{20 + (i % 16)}" for i in range(n_requests)]
 
 
-def _make_fleet_session(zoo, table, sample, k: int):
+def _make_fleet_session(zoo, table, sample, k: int, **kw):
     """Base session + K registered fine-tunes (head deltas of the base),
     each bound to its own task via resolve_task(model_id=)."""
-    sess = _make_session(zoo, table, sample)   # resolves 'sent' -> base
+    sess = _make_session(zoo, table, sample, **kw)  # 'sent' -> base
     rng = np.random.default_rng(7)
     base = zoo[0]
     width = int(base.W.shape[1])
@@ -199,19 +201,18 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
                "post-warmup window")
     emit_value("serving.share_hit_rate", st.share_hit_rate, "warm rows")
 
-    # -- overlap ablation: share-aware trunk lanes vs per-task lanes -----
-    # concurrent requests select overlapping row windows; the share-aware
-    # server embeds each distinct row once (cache + in-flight dedup) and
-    # warm traffic pays head-only cost, while per-task full-predict lanes
-    # recompute every window end to end
+    # -- overlap ablation: the share cache on vs off --------------------
+    # concurrent requests select overlapping row windows; with sharing on
+    # the server embeds each distinct row once (cache + in-flight dedup)
+    # and warm traffic pays head-only cost, while with sharing off every
+    # window runs the trunk again
     zoo_o, table_o, sample_o = _setup(n_rows, width=OVERLAP_TRUNK_WIDTH,
                                       name="serve-share")
-    sess_task = _make_session(zoo_o, table_o, sample_o)
-    srv_task = MorphingServer(session=sess_task, max_wait_s=0.002,
-                              share_lanes=False)
-    with srv_task:
-        t_task, _, _, _ = bench_server(srv_task, stmts, concurrency,
-                                       warm_all=True)
+    sess_off = _make_session(zoo_o, table_o, sample_o, enable_share=False)
+    srv_off = MorphingServer(session=sess_off, max_wait_s=0.002)
+    with srv_off:
+        t_off, _, _, _ = bench_server(srv_off, stmts, concurrency,
+                                      warm_all=True)
     sess_share = _make_session(zoo_o, table_o, sample_o)
     srv_share = MorphingServer(session=sess_share, max_wait_s=0.002)
     with srv_share:
@@ -230,17 +231,17 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
             list(pool.map(lambda s: srv_probe.predict(s, timeout=60.0),
                           [stmts[0]] * concurrency))
     dedup_probe = srv_probe.stats()
-    ref_o = sess_task.sql(stmts[0]).rows["_score"]
+    ref_o = sess_off.sql(stmts[0]).rows["_score"]
     got_o = outs_share[0].scores         # pool.map preserves order
     np.testing.assert_allclose(np.sort(got_o), np.sort(ref_o), atol=1e-5)
-    share_speedup = t_task / t_share
-    emit_value("serving.overlap_task_lane_rows_per_s",
-               rows_total / t_task, "full predict per lane")
+    share_speedup = t_off / t_share
+    emit_value("serving.overlap_no_share_rows_per_s",
+               rows_total / t_off, "sharing off: every row runs the trunk")
     emit_value("serving.overlap_share_rows_per_s",
                rows_total / t_share,
                f"hit_rate={st_share.share_hit_rate:.2f} "
                f"cold_dedup={cold_share.dedup_rate:.2f}")
-    emit_value("serving.speedup_share_vs_task_lanes", share_speedup,
+    emit_value("serving.speedup_share_vs_no_share", share_speedup,
                "x warm overlapping rows")
     emit_value("serving.dedup_probe_rate", dedup_probe.dedup_rate,
                f"{dedup_probe.dedup_rows} in-flight rows folded")
@@ -251,18 +252,17 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
 
     # -- delta fleet: K fine-tunes of one base share one embed lane -----
     # the heavy trunk runs once per distinct row window regardless of
-    # which fine-tune asked; per-task full-predict lanes (the ablation)
-    # recompute it K times and stage K trunk copies
+    # which fine-tune asked; with sharing off (the ablation) it runs for
+    # every request again
     zoo_d, table_d, sample_d = _setup(n_rows, width=OVERLAP_TRUNK_WIDTH,
                                       name="serve-delta")
     fleet_stmts = _fleet_statements(n_requests, DELTA_FLEET_K)
-    sess_dtask = _make_fleet_session(zoo_d, table_d, sample_d,
-                                     DELTA_FLEET_K)
-    srv_dtask = MorphingServer(session=sess_dtask, max_wait_s=0.002,
-                               share_lanes=False)
-    with srv_dtask:
-        t_dtask, _, _, _ = bench_server(srv_dtask, fleet_stmts,
-                                        concurrency, warm_all=True)
+    sess_doff = _make_fleet_session(zoo_d, table_d, sample_d,
+                                    DELTA_FLEET_K, enable_share=False)
+    srv_doff = MorphingServer(session=sess_doff, max_wait_s=0.002)
+    with srv_doff:
+        t_doff, _, _, _ = bench_server(srv_doff, fleet_stmts,
+                                       concurrency, warm_all=True)
     sess_fleet = _make_fleet_session(zoo_d, table_d, sample_d,
                                      DELTA_FLEET_K)
     srv_fleet = MorphingServer(session=sess_fleet, max_wait_s=0.002)
@@ -272,7 +272,7 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
     rows_fleet = _rows_served(sess_fleet, fleet_stmts)
 
     # parity: a served fine-tune matches its analytics answer
-    ref_d = sess_dtask.sql(fleet_stmts[0]).rows["_score"]
+    ref_d = sess_doff.sql(fleet_stmts[0]).rows["_score"]
     np.testing.assert_allclose(np.sort(outs_fleet[0].scores),
                                np.sort(ref_d), atol=1e-5)
     # the whole fleet rides ONE embed lane (shared base trunk identity)
@@ -288,14 +288,14 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
         f"delta fleet loaded {fleet_loaded}B >= {fleet_budget:.0f}B "
         f"(base {base_rm.stored_bytes}B + "
         f"{DELTA_FLEET_K}·delta {st_fleet.delta_stored_bytes}B)")
-    delta_speedup = t_dtask / t_fleet
-    emit_value("serving.delta_fleet_task_lane_rows_per_s",
-               rows_fleet / t_dtask, f"{DELTA_FLEET_K} full lanes")
+    delta_speedup = t_doff / t_fleet
+    emit_value("serving.delta_fleet_no_share_rows_per_s",
+               rows_fleet / t_doff, "sharing off")
     emit_value("serving.delta_fleet_share_rows_per_s",
                rows_fleet / t_fleet,
                f"1 embed lane, {DELTA_FLEET_K} heads, "
                f"hit_rate={st_fleet.share_hit_rate:.2f}")
-    emit_value("serving.speedup_delta_fleet_vs_task_lanes", delta_speedup,
+    emit_value("serving.speedup_delta_fleet_vs_no_share", delta_speedup,
                "x warm fleet rows")
     emit_value("serving.delta_fleet_loaded_bytes", fleet_loaded,
                f"budget {fleet_budget:.0f}")
@@ -334,33 +334,31 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
         "speedup_server_vs_per_request": speedup,
         "overlap": {
             "trunk_width": OVERLAP_TRUNK_WIDTH,
-            "task_lanes": {"wall_s": t_task,
-                           "rows_per_s_warm": rows_total / t_task},
-            "share_lanes": {"wall_s": t_share,
-                            "rows_per_s_warm": rows_total / t_share,
-                            "p95_latency_ms":
-                                st_share.p95_latency_s * 1e3,
-                            "share_hit_rate": st_share.share_hit_rate,
-                            "cold_dedup_rate": cold_share.dedup_rate,
-                            "dedup_probe_rate": dedup_probe.dedup_rate,
-                            "dedup_probe_rows": dedup_probe.dedup_rows,
-                            "embed_rows": st_share.embed_rows,
-                            "head_rows": st_share.head_rows},
-            "speedup_share_vs_task_lanes": share_speedup,
+            "no_share": {"wall_s": t_off,
+                         "rows_per_s_warm": rows_total / t_off},
+            "share": {"wall_s": t_share,
+                      "rows_per_s_warm": rows_total / t_share,
+                      "p95_latency_ms": st_share.p95_latency_s * 1e3,
+                      "share_hit_rate": st_share.share_hit_rate,
+                      "cold_dedup_rate": cold_share.dedup_rate,
+                      "dedup_probe_rate": dedup_probe.dedup_rate,
+                      "dedup_probe_rows": dedup_probe.dedup_rows,
+                      "embed_rows": st_share.embed_rows,
+                      "head_rows": st_share.head_rows},
+            "speedup_share_vs_no_share": share_speedup,
         },
         "delta_fleet": {
             "k": DELTA_FLEET_K,
             "trunk_width": OVERLAP_TRUNK_WIDTH,
-            "task_lanes": {"wall_s": t_dtask,
-                           "rows_per_s_warm": rows_fleet / t_dtask},
-            "share_lanes": {"wall_s": t_fleet,
-                            "rows_per_s_warm": rows_fleet / t_fleet,
-                            "p95_latency_ms":
-                                st_fleet.p95_latency_s * 1e3,
-                            "share_hit_rate": st_fleet.share_hit_rate,
-                            "lanes": st_fleet.lanes,
-                            "delta_tasks": st_fleet.delta_tasks},
-            "speedup_share_vs_task_lanes": delta_speedup,
+            "no_share": {"wall_s": t_doff,
+                         "rows_per_s_warm": rows_fleet / t_doff},
+            "share": {"wall_s": t_fleet,
+                      "rows_per_s_warm": rows_fleet / t_fleet,
+                      "p95_latency_ms": st_fleet.p95_latency_s * 1e3,
+                      "share_hit_rate": st_fleet.share_hit_rate,
+                      "lanes": st_fleet.lanes,
+                      "delta_tasks": st_fleet.delta_tasks},
+            "speedup_share_vs_no_share": delta_speedup,
             "base_stored_bytes": int(base_rm.stored_bytes),
             "delta_stored_bytes": int(st_fleet.delta_stored_bytes),
             "loaded_bytes": int(fleet_loaded),
@@ -376,13 +374,13 @@ def run(n_rows: int = N_ROWS, n_requests: int = N_REQUESTS,
             f"server {speedup:.2f}x < {TARGET_SPEEDUP}x target over "
             f"per-request execution at concurrency {concurrency}")
         assert share_speedup >= TARGET_SHARE_SPEEDUP, (
-            f"share-aware lanes {share_speedup:.2f}x < "
-            f"{TARGET_SHARE_SPEEDUP}x target over per-task lanes on the "
+            f"sharing on {share_speedup:.2f}x < "
+            f"{TARGET_SHARE_SPEEDUP}x target over sharing off on the "
             f"overlapping workload at concurrency {concurrency}")
         assert delta_speedup >= TARGET_DELTA_SPEEDUP, (
             f"delta fleet through the shared embed lane "
             f"{delta_speedup:.2f}x < {TARGET_DELTA_SPEEDUP}x target over "
-            f"{DELTA_FLEET_K} per-task lanes at concurrency {concurrency}")
+            f"sharing off at concurrency {concurrency}")
     if json_path:
         Path(json_path).write_text(json.dumps(result, indent=2,
                                               sort_keys=True))

@@ -465,7 +465,6 @@ class DispatchServer:
         self._serve_kw = {"max_wait_s": max_wait_s,
                           "idle_wait_s": idle_wait_s,
                           "mem_cap_bytes": mem_cap_bytes,
-                          "share_lanes": True,
                           "stop_timeout_s": stop_timeout_s}
         self._worker_opts = {"hb_interval_s": heartbeat_s,
                              "result_timeout_s": lease_timeout_s}

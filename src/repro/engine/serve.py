@@ -26,16 +26,16 @@ delta task count and byte accounting.
   drain-on-stop); tasks whose resolved models share a trunk fingerprint
   (``ResolvedModel.trunk_fp``, tracked by the DecoupledStore layer-tensor
   identity) feed one lane;
-- a lane's coalesced batch consults the :class:`VectorShareCache` first
-  through the batched row-granular API (``get_many`` — one vectorized
-  fingerprint pass over the whole chunk), so warm rows cost a gather,
-  not a forward pass;
+- a lane's coalesced batch runs the share step, ``CacheChain.step`` over
+  the session's ``cache_chain`` (exact tier, then the ANN tier when it is
+  on): one vectorized fingerprint pass and gather over the whole chunk
+  (``lookup_many``), so warm rows cost a gather, not a forward pass;
 - identical in-flight rows are single-flight deduplicated: each lane has
   one worker, batches serialize, and within a batch only the *unique*
   missing rows run through the trunk (``ServerStats.dedup_rows`` counts
-  the folded duplicates); results write back via ``put_many`` before the
-  next batch collects, so N concurrent identical requests compute one
-  embedding;
+  the folded duplicates); results write back via ``insert_many`` before
+  the next batch collects, so N concurrent identical requests compute
+  one embedding;
 - row budgets come from Eq. 11 sized per stage (``cost.split_profile``):
   the embed lane batches to the trunk's budget, the head stage to its
   own (much larger) budget, executed through the backend's head-only
@@ -50,9 +50,6 @@ delta task count and byte accounting.
     out = server.result(rid)          # ServeResult: scores + latency
     server.stats().share_hit_rate
     server.stop()                     # drains the queues, joins workers
-
-``share_lanes=False`` restores the per-task full-predict lanes (the
-ablation baseline ``benchmarks/bench_serving.py`` measures against).
 """
 from __future__ import annotations
 
@@ -126,7 +123,7 @@ class ServerStats:
     share_hit_rate_by_lane: Dict[str, float] = field(default_factory=dict)
     # fine-tune delta serving: tasks whose resolved model is a delta
     # variant (ResolvedModel.base_model_id) riding a shared trunk lane
-    lanes: int = 0                   # live embed/predict lanes
+    lanes: int = 0                   # live trunk lanes
     tasks_by_lane: Dict[str, int] = field(default_factory=dict)
     # mesh dimension: how many devices the trunk embed lanes span, and
     # the measured aggregate embed rate across them (rows the trunks
@@ -207,17 +204,15 @@ class _HeadStage:
 
 @dataclass
 class _Lane:
-    """One serving lane: a batcher plus the embed/head stage specs.
-
-    With share lanes the key is the trunk fingerprint and ``heads`` maps
-    every task feeding the lane to its head stage; in legacy mode the
-    key is the task and ``spec`` executes the fused full predict.
+    """One trunk lane: a batcher plus the embed/head stage specs. The key
+    is the trunk fingerprint (the version string for a BLOB model) and
+    ``heads`` maps every task feeding the lane to its head stage.
     """
     key: str
     device: str
     batcher: ContinuousBatcher
-    spec: InferSpec                  # embed spec (share) / predict (legacy)
-    batch_rows: int                  # Eq. 11 embed (or predict) row budget
+    spec: InferSpec                  # the trunk's embed spec
+    batch_rows: int                  # Eq. 11 embed row budget
     heads: Dict[str, _HeadStage] = field(default_factory=dict)
     in_dim: int = 0                  # trunk input width (0 = adapt per batch)
     requests_by_task: Dict[str, int] = field(default_factory=dict)
@@ -249,7 +244,7 @@ class MorphingServer:
                  config: Optional[EngineConfig] = None,
                  max_wait_s: float = 0.002, idle_wait_s: float = 0.05,
                  mem_cap_bytes: float = 2e9, nrows_hint: int = 2048,
-                 share_lanes: bool = True, devices: Optional[int] = None,
+                 devices: Optional[int] = None,
                  stop_timeout_s: float = 30.0,
                  policy: Optional[AdmissionPolicy] = None, **session_kw):
         if devices is not None:
@@ -277,7 +272,6 @@ class MorphingServer:
         self.idle_wait_s = idle_wait_s
         self.mem_cap_bytes = mem_cap_bytes
         self.nrows_hint = nrows_hint
-        self.share_lanes = share_lanes
         self.stop_timeout_s = stop_timeout_s
         # admission policy is applied to every lane; None keeps the
         # legacy unbounded FIFO lanes. The shared EngineConfig is the
@@ -442,7 +436,7 @@ class MorphingServer:
     def _lane_for(self, task: str) -> _Lane:
         sess = self.session
         rm = sess.models[task]
-        key = ((rm.trunk_fp or rm.version) if self.share_lanes else task)
+        key = rm.trunk_fp or rm.version
         lane = self._lanes.get(key)
         if lane is not None and task in lane.requests_by_task:
             return lane
@@ -462,7 +456,7 @@ class MorphingServer:
                 # its own head stage; the trunk work is shared. Mutations
                 # go under the lane lock: stats()/reset_telemetry()
                 # iterate these dicts while traffic registers new tasks
-                if self.share_lanes and task not in lane.heads:
+                if task not in lane.heads:
                     backend = (sess.backends.get(lane.device)
                                or default_host_backend())
                     stage = self._head_stage(task, rm, backend)
@@ -478,85 +472,73 @@ class MorphingServer:
         device = choose_device(rm.profile, self.nrows_hint,
                                sess.devices, sess.hw)
         backend = sess.backends.get(device) or default_host_backend()
-        if not self.share_lanes:
-            batch_rows = choose_batch_size(
-                rm.profile, device, candidates=_LANE_BATCH_CANDIDATES,
-                mem_cap_bytes=self.mem_cap_bytes, hw=sess.hw)
-            # staging identity is the trunk fingerprint here too (the
-            # session staged weights under it): the per-task ablation
-            # lanes must not re-stage a duplicate trunk per task
-            spec = InferSpec(
-                kind="predict", task=rm.task, col="x", out="y",
-                table=_SHARE_TABLE, version=(rm.trunk_fp or rm.version),
-                model=rm, batch_size=batch_rows, share=None,
-                stats=BatcherStats())
-            lane = _Lane(key=key, device=device, batcher=None,  # type: ignore
-                         spec=spec, batch_rows=batch_rows)
-            step = self._legacy_step(lane, backend)
-        else:
-            embed_prof, _ = split_profile(rm.profile, rm.head_dim)
-            batch_rows = choose_batch_size(
-                embed_prof, device, candidates=_LANE_BATCH_CANDIDATES,
-                mem_cap_bytes=self.mem_cap_bytes, hw=sess.hw)
-            # mesh lanes budget against aggregate throughput: each of the
-            # N devices takes batch/N rows, so the Eq. 11 optimum for one
-            # device scales to N devices at the same per-device latency
-            # and memory footprint (capped at the candidate ceiling)
-            n_dev = int(getattr(backend, "device_count", 1))
-            if n_dev > 1:
-                batch_rows = min(batch_rows * n_dev,
-                                 _LANE_BATCH_CANDIDATES[-1])
-            # the staging identity is the trunk fingerprint (matching
-            # MorphingSession._stage_all): fine-tunes riding this lane
-            # reuse the one staged base trunk instead of re-staging K
-            # identical copies; the share cache is keyed by the lane's
-            # trunk fingerprint explicitly in _embed
-            spec = InferSpec(
-                kind="embed", task=rm.task, col="x", out="f",
-                table=_SHARE_TABLE, version=(rm.trunk_fp or rm.version),
-                model=rm, batch_size=batch_rows, share=None,
-                stats=BatcherStats())
-            lane = _Lane(key=key, device=device, batcher=None,  # type: ignore
-                         spec=spec, batch_rows=batch_rows,
-                         in_dim=int(rm.in_dim or 0))
-            lane.heads[rm.task] = self._head_stage(rm.task, rm, backend)
-            step = self._share_step(lane, backend)
+        embed_prof, _ = split_profile(rm.profile, rm.head_dim)
+        batch_rows = choose_batch_size(
+            embed_prof, device, candidates=_LANE_BATCH_CANDIDATES,
+            mem_cap_bytes=self.mem_cap_bytes, hw=sess.hw)
+        # mesh lanes budget against aggregate throughput: each of the N
+        # devices takes batch/N rows, so the Eq. 11 optimum for one
+        # device scales to N devices at the same per-device latency and
+        # memory footprint (capped at the candidate ceiling)
+        n_dev = int(getattr(backend, "device_count", 1))
+        if n_dev > 1:
+            batch_rows = min(batch_rows * n_dev, _LANE_BATCH_CANDIDATES[-1])
+        # the staging identity is the trunk fingerprint (matching
+        # MorphingSession._stage_all): fine-tunes riding this lane reuse
+        # the one staged base trunk instead of re-staging K identical
+        # copies; the share cache is keyed by the lane's trunk
+        # fingerprint explicitly in _share_step
+        spec = InferSpec(
+            kind="embed", task=rm.task, col="x", out="f",
+            table=_SHARE_TABLE, version=(rm.trunk_fp or rm.version),
+            model=rm, batch_size=batch_rows, share=None,
+            stats=BatcherStats())
+        lane = _Lane(key=key, device=device, batcher=None,  # type: ignore
+                     spec=spec, batch_rows=batch_rows,
+                     in_dim=int(rm.in_dim or 0))
+        lane.heads[rm.task] = self._head_stage(rm.task, rm, backend)
         lane.batcher = ContinuousBatcher(
-            step, batch_size=batch_rows, size_of=lambda p: len(p[1]),
+            self._share_step(lane, backend), batch_size=batch_rows,
+            size_of=lambda p: len(p[1]),
             max_wait_s=self.max_wait_s, idle_wait_s=self.idle_wait_s,
             name=key, policy=self.policy)
         return lane
 
     # -- lane execution ----------------------------------------------------
-    def _legacy_step(self, lane: _Lane, backend: ExecutionBackend):
-        """Per-task full-predict step (the pre-share serving path)."""
-        def step(payloads: List[Tuple[str, np.ndarray]]) -> List[np.ndarray]:
-            arrs = [np.asarray(p, np.float32) for _, p in payloads]
-            lens = [len(a) for a in arrs]
-            out = np.asarray(
-                backend.run_infer(lane.spec, {"x": _stack(arrs)})["y"])
-            offs = np.cumsum([0] + lens)
-            return [out[a:b] for a, b in zip(offs[:-1], offs[1:])]
-        return step
-
     def _share_step(self, lane: _Lane, backend: ExecutionBackend):
-        """Trunk-lane step: batched cache-chain lookup -> single-flight
-        dedup -> trunk forward on unique missing rows -> write-back ->
-        per-task head stages."""
-        # with the ANN tier enabled the lanes consult the whole chain
-        # (exact tier first, calibrated nearest-neighbor reuse for the
-        # residual misses); otherwise just the exact tier
-        share = (self.session.cache_chain
-                 if getattr(self.session, "ann", None) is not None
-                 else self.session.share)
-        use_share = self.session.enable_share
+        """Trunk-lane step: the share step over the coalesced chunk
+        (``CacheChain.step``: lookup -> single-flight dedup -> trunk
+        forward on the unique rows to compute -> scatter -> insert),
+        then the per-task head stages. With sharing off every row runs
+        the trunk."""
+        chain = (self.session.cache_chain if self.session.enable_share
+                 else None)
+        spec = lane.spec
+
+        def trunk(A: np.ndarray) -> np.ndarray:
+            # backend.run_infer is looked up per call, so a wrapper set
+            # on the backend after the lane was built is honoured
+            return backend.run_infer(spec, {"x": A})[spec.out]
 
         def step(payloads: List[Tuple[str, np.ndarray]]) -> List[np.ndarray]:
             with spans.span("lane.stack"):
                 arrs = [np.asarray(p, np.float32) for _, p in payloads]
                 lens = [len(a) for a in arrs]
                 X = _stack(arrs, width=lane.in_dim or None)
-            E = self._embed(lane, backend, share if use_share else None, X)
+            if chain is not None:
+                st = chain.step(_SHARE_TABLE, lane.key, X, trunk,
+                                version=lane.key)
+                E = st.E
+                with lane.lock:
+                    lane.share_hits += st.hits
+                    lane.share_misses += st.misses
+                    lane.approx_hits += st.approx_hits
+                    lane.false_accepts += st.false_accepts
+                    lane.dedup_rows += st.folded
+            elif len(X):
+                E = np.asarray(trunk(X))
+            else:
+                E = X                    # no request has rows: E unread
             offs = np.cumsum([0] + lens)
             outs: List[np.ndarray] = []
             with spans.span("lane.head"):
@@ -565,79 +547,6 @@ class MorphingServer:
                                 else np.zeros(0, np.float32))
             return outs
         return step
-
-    def _embed(self, lane: _Lane, backend: ExecutionBackend,
-               share, X: np.ndarray) -> np.ndarray:
-        """Embeddings for one coalesced chunk: cache rows are gathered
-        (exactly or via the ANN tier's calibrated reuse), unique missing
-        rows computed once, results written back. Audited approx hits
-        are recomputed exactly, reported via ``record_audit`` and served
-        exact — the serving path keeps the tier's radius honest."""
-        n = len(X)
-        if n == 0:
-            return np.zeros((0, 1), np.float32)
-        if share is None:
-            return np.asarray(
-                backend.run_infer(lane.spec, {"x": X})[lane.spec.out])
-        look = share.lookup_many(_SHARE_TABLE, lane.key, X,
-                                 version=lane.key)
-        keys, miss = look.keys, look.miss
-        n_miss = int(miss.sum())
-        n_approx = len(look.approx_idx)
-        # rows that must run the trunk: real misses plus the audit
-        # sample of the approximate hits
-        need = miss.copy()
-        if len(look.audit_idx):
-            need[look.audit_idx] = True
-        if not need.any():
-            with lane.lock:
-                lane.share_hits += n - n_approx
-                lane.approx_hits += n_approx
-            return look.found
-        # single-flight dedup: identical in-flight rows (across the
-        # coalesced requests of this batch) compute once. The lane's
-        # single worker serializes batches, so rows computed here are in
-        # the cache before any later batch looks them up.
-        with spans.span("lane.dedup"):
-            need_idx = np.flatnonzero(need)
-            uniq, first = np.unique(keys[need_idx], return_index=True)
-            comp_idx = need_idx[first]
-        computed = np.asarray(
-            backend.run_infer(lane.spec, {"x": X[comp_idx]})[lane.spec.out],
-            np.float32)
-        with spans.span("lane.scatter"):
-            E = (np.asarray(look.found, np.float32)
-                 if look.found is not None
-                 else np.zeros((n, computed.shape[1]), np.float32))
-            fa = 0
-            if len(look.audit_idx):
-                exact = computed[np.searchsorted(uniq,
-                                                 keys[look.audit_idx])]
-                errs = np.linalg.norm(
-                    E[look.audit_idx].astype(np.float64) - exact, axis=1)
-                order = np.argsort(look.approx_idx, kind="stable")
-                loc = order[np.searchsorted(look.approx_idx[order],
-                                            look.audit_idx)]
-                record = getattr(share, "record_audit", None)
-                if record is not None:
-                    record(_SHARE_TABLE, lane.key, lane.key,
-                           look.approx_dist[loc], errs)
-                ann = getattr(share, "ann", None)
-                if ann is not None:
-                    fa = int((errs > ann.cfg.error_bound).sum())
-            # computed[j] embeds uniq[j] (np.unique sorts): scatter back
-            # to every duplicate needed row in one searchsorted — audited
-            # rows get their exact recomputation, not the approximation
-            E[need_idx] = computed[np.searchsorted(uniq, keys[need_idx])]
-        share.insert_many(_SHARE_TABLE, lane.key, keys[comp_idx],
-                          X[comp_idx], computed, version=lane.key)
-        with lane.lock:
-            lane.share_hits += n - n_miss - n_approx
-            lane.share_misses += n_miss
-            lane.approx_hits += n_approx
-            lane.false_accepts += fa
-            lane.dedup_rows += len(need_idx) - len(comp_idx)
-        return E
 
     # -- request admission -------------------------------------------------
     def resolve_task(self, name: str, X: np.ndarray, y: np.ndarray,
@@ -803,19 +712,15 @@ class MorphingServer:
                 st.requests_by_task[task] = \
                     st.requests_by_task.get(task, 0) + c
             st.batches += len(lane_sizes)
-            if heads:                            # share-aware lane
-                st.embed_rows += lane.spec.stats.rows
-                st.embed_batches += lane.spec.stats.batches
-                st.infer_seconds += lane.spec.stats.infer_seconds
-                embed_seconds += lane.spec.stats.infer_seconds
-                for h in heads:
-                    st.rows += h.spec.stats.rows     # every served row
-                    st.head_rows += h.spec.stats.rows  # passes one head
-                    st.head_batches += h.spec.stats.batches
-                    st.infer_seconds += h.spec.stats.infer_seconds
-            else:                                # legacy full-predict lane
-                st.rows += lane.spec.stats.rows
-                st.infer_seconds += lane.spec.stats.infer_seconds
+            st.embed_rows += lane.spec.stats.rows
+            st.embed_batches += lane.spec.stats.batches
+            st.infer_seconds += lane.spec.stats.infer_seconds
+            embed_seconds += lane.spec.stats.infer_seconds
+            for h in heads:
+                st.rows += h.spec.stats.rows         # every served row
+                st.head_rows += h.spec.stats.rows    # passes one head
+                st.head_batches += h.spec.stats.batches
+                st.infer_seconds += h.spec.stats.infer_seconds
             lat.extend(lane_lat)
             coalesced.extend(lane_sizes)
             waits.extend(lane.batcher.queue_wait_snapshot())

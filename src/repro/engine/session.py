@@ -705,13 +705,11 @@ class MorphingSession:
         if not self.enable_share:
             return np.asarray(rm.features(np.asarray(rows)),
                               np.float32), len(rows)
-        c0 = self.cache_chain.computed_rows
-        E = self.cache_chain.get_or_embed(
+        st = self.cache_chain.step(
             tname, col, rows,
             lambda A: np.asarray(rm.features(np.asarray(A)), np.float32),
             version=(rm.trunk_fp or rm.version))
-        return np.asarray(E, np.float32), \
-            self.cache_chain.computed_rows - c0
+        return np.asarray(st.E, np.float32), st.computed
 
     def _similarity_scores(self, tname: str, col: str, rows: np.ndarray,
                            query, rm: Optional[ResolvedModel]

@@ -13,13 +13,15 @@ share their base's cached embeddings. The paper pairs sharing with SIMD
 vectorization — our TPU analogue is the fused normalize+project Pallas
 kernel (repro.kernels.fused_embed); on host we batch-vectorize with
 numpy (SIMD via BLAS), including the one-pass murmur-style row
-fingerprints ``get_many``/``put_many`` ride.
+fingerprints the row tier's ``lookup_many``/``insert_many`` ride.
+``CacheChain.step`` is the one share step over the row tiers: lookup,
+single-flight dedup, the embed call on the distinct rows to compute,
+scatter and insert, for the served lanes and the engine's callers alike.
 """
 from __future__ import annotations
 
 import hashlib
 import threading
-import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -365,71 +367,17 @@ class VectorShareCache:
             _, old = self._mem.popitem(last=False)
             self._used -= old.nbytes
 
-    # -- batched row-granular tier (serving hot path) ----------------------
-    def get_many(self, table: str, column: str, rows: np.ndarray,
-                 version: str = "v1"
-                 ) -> Tuple[np.ndarray, Optional[np.ndarray], np.ndarray]:
-        """Batched row-granular lookup: fingerprint the whole chunk in
-        one vectorized pass and gather every cached row in one
-        ``searchsorted`` + fancy index — no per-row Python anywhere.
-
-        Returns ``(keys, found, miss)``: ``keys`` (uint64 fingerprints)
-        identify rows for :meth:`put_many`; ``found`` is an ``(n, width)``
-        array whose *hit* rows are filled — rows flagged by ``miss`` hold
-        unspecified data and must be overwritten by the caller (one plain
-        gather is ~20x cheaper than a masked scatter on the all-hit warm
-        path). ``found`` is ``None`` when this key space has no cached
-        rows yet; ``miss[i]`` is True when row i must be computed.
-        Hit/miss stats are counted per *row* — the serving analogue of
-        the chunk-level counts ``get_or_embed`` keeps.
-        """
-        look = self.lookup_many(table, column, rows, version)
-        return look.keys, look.found, look.miss
-
-    def put_many(self, table: str, column: str, keys: np.ndarray,
-                 rows: np.ndarray, version: str = "v1") -> None:
-        """Write computed rows back under keys from :meth:`get_many`."""
-        rows = np.asarray(rows)
-        keys = np.asarray(keys, np.uint64)
-        if len(keys) == 0:
-            return
-        if len(keys) != len(rows):
-            raise ValueError(f"{len(keys)} keys for {len(rows)} rows")
-        bk = self._blockkey(table, column, version)
-        with spans.span("share.insert"), self._lock:
-            spans.count("share.insert_rows", len(keys))
-            block = self._rows.get(bk)
-            if block is None:
-                block = _RowBlock(rows.shape[1], rows.dtype,
-                                  cap=max(256, len(rows)))
-                self._rows[bk] = block
-            self._rows.move_to_end(bk)
-            self._rows_used += block.put(keys, rows)
-            while (self._rows_used + self._used > self.capacity
-                   and len(self._rows) > 1):
-                with spans.span("share.evict"):
-                    _, old = self._rows.popitem(last=False)
-                    self._rows_used -= old.nbytes
-                    spans.count("share.evicted_rows", old.used)
-            # a lone block must not grow unbounded (it would also starve
-            # the chunk tier forever): shed its oldest rows until the
-            # combined usage fits
-            while self._rows_used + self._used > self.capacity:
-                with spans.span("share.evict"):
-                    held = block.used
-                    freed = block.drop_oldest()
-                    spans.count("share.evicted_rows", held - block.used)
-                if freed == 0:
-                    break
-                self._rows_used -= freed
-
-    # -- CacheTier protocol -------------------------------------------------
+    # -- CacheTier protocol: the batched row-granular tier -----------------
     def lookup_many(self, table: str, column: str, rows: np.ndarray,
                     version: str = "v1", *,
                     keys: Optional[np.ndarray] = None) -> TierLookup:
-        """:class:`CacheTier` lookup: exact fingerprint equality. With
-        precomputed ``keys`` the rows are not re-hashed (the chain path
-        fingerprints once for all tiers)."""
+        """:class:`CacheTier` lookup: exact fingerprint equality. The
+        whole chunk is fingerprinted in one vectorized pass (skipped when
+        the chain hands in precomputed ``keys``) and every cached row is
+        gathered in one sorted search plus one fancy index, with no
+        per-row Python. Miss rows of ``found`` hold the gather's clamped
+        garbage: one plain gather is ~20x cheaper than a masked scatter
+        on the all-hit warm path. Hits and misses count per row."""
         with spans.span("share.lookup"):
             if keys is None:
                 with spans.span("share.fingerprint"):
@@ -455,34 +403,44 @@ class VectorShareCache:
     def insert_many(self, table: str, column: str, keys: np.ndarray,
                     rows: np.ndarray, embs: np.ndarray,
                     version: str = "v1") -> None:
-        """:class:`CacheTier` insert. The exact tier keys purely by
-        fingerprint, so the raw ``rows`` are unused here (the ANN tier
+        """:class:`CacheTier` insert: write computed embeddings back under
+        the keys :meth:`lookup_many` returned. The exact tier keys purely
+        by fingerprint, so the raw ``rows`` are unused here (the ANN tier
         needs them to index input space)."""
         del rows
-        self.put_many(table, column, keys, embs, version)
-
-    def get_row(self, table: str, column: str, row: np.ndarray,
-                version: str = "v1") -> Optional[np.ndarray]:
-        """Single-row lookup. Deprecated: use :meth:`lookup_many` (or
-        the batched :meth:`get_many`) — per-row calls forfeit the
-        vectorized fingerprint/gather path."""
-        warnings.warn("VectorShareCache.get_row is deprecated; use "
-                      "lookup_many/get_many", DeprecationWarning,
-                      stacklevel=2)
-        _, found, miss = self.get_many(table, column,
-                                       np.asarray(row)[None], version)
-        return None if (found is None or miss[0]) else found[0]
-
-    def put_row(self, table: str, column: str, row: np.ndarray,
-                emb: np.ndarray, version: str = "v1") -> None:
-        """Single-row insert. Deprecated: use :meth:`insert_many` (or
-        the batched :meth:`put_many`)."""
-        warnings.warn("VectorShareCache.put_row is deprecated; use "
-                      "insert_many/put_many", DeprecationWarning,
-                      stacklevel=2)
-        row = np.asarray(row)[None]
-        self.put_many(table, column, fingerprint_rows(row),
-                      np.asarray(emb)[None], version)
+        embs = np.asarray(embs)
+        keys = np.asarray(keys, np.uint64)
+        if len(keys) == 0:
+            return
+        if len(keys) != len(embs):
+            raise ValueError(f"{len(keys)} keys for {len(embs)} rows")
+        bk = self._blockkey(table, column, version)
+        with spans.span("share.insert"), self._lock:
+            spans.count("share.insert_rows", len(keys))
+            block = self._rows.get(bk)
+            if block is None:
+                block = _RowBlock(embs.shape[1], embs.dtype,
+                                  cap=max(256, len(embs)))
+                self._rows[bk] = block
+            self._rows.move_to_end(bk)
+            self._rows_used += block.put(keys, embs)
+            while (self._rows_used + self._used > self.capacity
+                   and len(self._rows) > 1):
+                with spans.span("share.evict"):
+                    _, old = self._rows.popitem(last=False)
+                    self._rows_used -= old.nbytes
+                    spans.count("share.evicted_rows", old.used)
+            # a lone block must not grow unbounded (it would also starve
+            # the chunk tier forever): shed its oldest rows until the
+            # combined usage fits
+            while self._rows_used + self._used > self.capacity:
+                with spans.span("share.evict"):
+                    held = block.used
+                    freed = block.drop_oldest()
+                    spans.count("share.evicted_rows", held - block.used)
+                if freed == 0:
+                    break
+                self._rows_used -= freed
 
     @staticmethod
     def _blockkey(table: str, column: str, version: str) -> str:
@@ -905,20 +863,36 @@ class AnnShareTier:
                                     float((errors[ok] / dists[ok]).max()))
 
 
+@dataclass
+class ShareStep:
+    """What one :meth:`CacheChain.step` served and did. Of its rows,
+    ``hits`` came exactly from the cache, ``approx_hits`` from the ANN
+    tier and ``misses`` from neither; ``folded`` duplicates among the
+    rows to compute (misses plus the audit sample of approximate hits)
+    were removed by single-flight dedup, ``computed`` distinct rows ran
+    ``embed_fn``, and ``false_accepts`` audited approximate hits were
+    off their exact recomputation by more than the tier's bound."""
+    E: np.ndarray
+    hits: int = 0
+    misses: int = 0
+    approx_hits: int = 0
+    false_accepts: int = 0
+    folded: int = 0
+    computed: int = 0
+
+
 class CacheChain:
     """Compose :class:`CacheTier`s into one cache: lookups consult
     tiers in order (exact first), each tier serving only the residual
-    misses of the previous one; inserts broadcast to every tier. Also
-    carries the chunk-style ``get_or_embed`` entry point the analytics
-    embed nodes use, which runs the full audit protocol: audited
-    approx hits are recomputed exactly, compared, reported back via
-    ``record_audit``, and served exact."""
+    misses of the previous one; inserts broadcast to every tier.
+    :meth:`step` is the one share step every row-granular caller runs:
+    the served lanes, similarity scoring and the analytics embed nodes
+    with the ANN tier on."""
 
     def __init__(self, tiers: Sequence[CacheTier]):
         if not tiers:
             raise ValueError("CacheChain needs at least one tier")
         self.tiers: List[CacheTier] = list(tiers)
-        self.computed_rows = 0     # rows embed_fn actually computed
 
     def lookup_many(self, table: str, column: str, rows: np.ndarray,
                     version: str = "v1", *,
@@ -953,7 +927,8 @@ class CacheChain:
                     rows: np.ndarray, embs: np.ndarray,
                     version: str = "v1") -> None:
         for tier in self.tiers:
-            tier.insert_many(table, column, keys, rows, embs, version)
+            tier.insert_many(table, column, keys, rows, embs,
+                             version=version)
 
     def record_audit(self, table: str, column: str, version: str,
                      dists: np.ndarray, errors: np.ndarray) -> None:
@@ -969,45 +944,78 @@ class CacheChain:
                 return tier
         return None
 
+    def step(self, table: str, column: str, rows: np.ndarray,
+             embed_fn: Callable[[np.ndarray], np.ndarray],
+             version: str = "v1") -> ShareStep:
+        """Embeddings for one chunk of rows: hit rows are gathered
+        (exactly, or through the ANN tier's calibrated reuse), the rows
+        to compute are deduplicated so each distinct row runs
+        ``embed_fn`` once, and the results are scattered back and
+        inserted into every tier. Audited approximate hits are
+        recomputed, reported through ``record_audit`` and served exact,
+        which keeps the ANN tier's radius honest. ``embed_fn`` is not
+        called for an empty chunk."""
+        rows = np.asarray(rows)
+        n = len(rows)
+        if n == 0:
+            return ShareStep(np.zeros((0, 1), np.float32))
+        look = self.lookup_many(table, column, rows, version)
+        keys, miss = look.keys, look.miss
+        n_miss = int(miss.sum())
+        n_approx = len(look.approx_idx)
+        out = ShareStep(look.found, hits=n - n_miss - n_approx,
+                        misses=n_miss, approx_hits=n_approx)
+        # rows that must run embed_fn: real misses plus the audit sample
+        # of the approximate hits
+        need = miss.copy()
+        if len(look.audit_idx):
+            need[look.audit_idx] = True
+        if not need.any():
+            return out
+        # single-flight dedup: identical rows of the chunk (in a lane,
+        # across its coalesced requests) compute once. A lane's single
+        # worker serializes its batches, so rows computed here are in
+        # the cache before any later batch looks them up.
+        with spans.span("lane.dedup"):
+            need_idx = np.flatnonzero(need)
+            uniq, first = np.unique(keys[need_idx], return_index=True)
+            comp_idx = need_idx[first]
+        computed = np.asarray(embed_fn(rows[comp_idx]), np.float32)
+        with spans.span("lane.scatter"):
+            E = (np.asarray(look.found, np.float32)
+                 if look.found is not None
+                 else np.zeros((n, computed.shape[1]), np.float32))
+            if len(look.audit_idx):
+                exact = computed[np.searchsorted(uniq,
+                                                 keys[look.audit_idx])]
+                errs = np.linalg.norm(
+                    E[look.audit_idx].astype(np.float64) - exact, axis=1)
+                order = np.argsort(look.approx_idx, kind="stable")
+                loc = order[np.searchsorted(look.approx_idx[order],
+                                            look.audit_idx)]
+                self.record_audit(table, column, version,
+                                  look.approx_dist[loc], errs)
+                ann = self.ann
+                if ann is not None:
+                    out.false_accepts = int(
+                        (errs > ann.cfg.error_bound).sum())
+            # computed[j] embeds uniq[j] (np.unique sorts): scatter back
+            # to every duplicate needed row in one searchsorted; audited
+            # rows get their exact recomputation, not the approximation
+            E[need_idx] = computed[np.searchsorted(uniq, keys[need_idx])]
+        self.insert_many(table, column, keys[comp_idx], rows[comp_idx],
+                         computed, version)
+        out.E = E
+        out.folded = len(need_idx) - len(comp_idx)
+        out.computed = len(comp_idx)
+        return out
+
     def get_or_embed(self, table: str, column: str, data: np.ndarray,
                      embed_fn: Callable[[np.ndarray], np.ndarray],
                      version: str = "v1") -> np.ndarray:
-        """Row-granular replacement for the chunk-level
-        ``VectorShareCache.get_or_embed``: hit rows gather from the
-        chain, miss rows embed once per distinct fingerprint
-        (single-flight within the call), and audited approx hits are
-        recomputed, compared against the bound, and refreshed exact."""
-        rows = np.asarray(data)
-        n = len(rows)
-        if n == 0:
-            return np.asarray(embed_fn(rows))
-        tl = self.lookup_many(table, column, rows, version)
-        need = tl.miss.copy()
-        if len(tl.audit_idx):
-            need[tl.audit_idx] = True
-        if not need.any():
-            return tl.found
-        cidx = np.flatnonzero(need)
-        uniq, first = np.unique(tl.keys[cidx], return_index=True)
-        comp_idx = cidx[first]
-        computed = np.asarray(embed_fn(rows[comp_idx]))
-        self.computed_rows += len(comp_idx)
-        E = tl.found
-        if E is None:
-            E = np.zeros((n, computed.shape[1]), computed.dtype)
-        if len(tl.audit_idx):
-            exact = computed[np.searchsorted(uniq, tl.keys[tl.audit_idx])]
-            errs = np.linalg.norm(
-                E[tl.audit_idx].astype(np.float64) - exact, axis=1)
-            order = np.argsort(tl.approx_idx, kind="stable")
-            loc = order[np.searchsorted(tl.approx_idx[order],
-                                        tl.audit_idx)]
-            self.record_audit(table, column, version,
-                              tl.approx_dist[loc], errs)
-        E[cidx] = computed[np.searchsorted(uniq, tl.keys[cidx])]
-        self.insert_many(table, column, tl.keys[comp_idx],
-                         rows[comp_idx], computed, version)
-        return E
+        """The chunk tier's entry point over :meth:`step`, for
+        ``ExecutionBackend.run_infer``'s embed nodes."""
+        return self.step(table, column, data, embed_fn, version).E
 
 
 def simd_normalize_embed(X: np.ndarray, W: np.ndarray,

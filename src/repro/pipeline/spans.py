@@ -27,7 +27,8 @@ SPAN_NAMES: Tuple[str, ...] = (
     "engine.submit", "engine.parse", "engine.filter",
     # the lane worker: waiting for requests, the step, publishing results
     "lane.collect", "lane.step", "lane.publish",
-    # children of lane.step
+    # children of lane.step; lane.dedup and lane.scatter open inside the
+    # share step, CacheChain.step in pipeline/share.py
     "lane.stack", "lane.dedup", "lane.scatter", "lane.head",
     # the share cache
     "share.lookup", "share.fingerprint", "share.search", "share.resort",

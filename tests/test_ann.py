@@ -3,7 +3,6 @@ reuse (error-bounded vs the exact oracle, hypothesis property tests),
 the CacheTier/CacheChain protocol, SIMILARITY query lowering, and the
 shared EngineConfig construction surface."""
 import tempfile
-import warnings
 
 import numpy as np
 import pytest
@@ -291,22 +290,6 @@ def test_property_recall_floor_seeded_corpus(seed):
         * (r * 0.1)
     tl = ann.lookup_many("t", "c", probe)
     assert tl.hits / len(probe) >= 0.95
-
-
-# -- deprecated row-level wrappers -----------------------------------------
-
-def test_get_row_put_row_deprecated_but_working():
-    cache = VectorShareCache()
-    row = np.arange(6, dtype=np.float32)
-    emb = np.ones(3, np.float32)
-    with pytest.warns(DeprecationWarning):
-        assert cache.get_row("t", "c", row) is None
-    with pytest.warns(DeprecationWarning):
-        cache.put_row("t", "c", row, emb)
-    with pytest.warns(DeprecationWarning):
-        got = cache.get_row("t", "c", row)
-    np.testing.assert_allclose(got, emb)
-    assert cache.stats.hits >= 1 and cache.stats.misses >= 1
 
 
 # -- SIMILARITY parsing + lowering -----------------------------------------

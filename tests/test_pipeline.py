@@ -173,35 +173,24 @@ def test_fingerprint_rows_matches_content():
     assert len(set(fingerprint_rows(Z).tolist())) == 32
 
 
-def test_share_cache_get_many_row_granular():
+def test_share_cache_lookup_many_row_granular():
     cache = VectorShareCache()
     rng = np.random.default_rng(1)
     X = rng.standard_normal((20, 8)).astype(np.float32)
     E = np.tanh(X @ np.ones((8, 4), np.float32))
-    keys, found, miss = cache.get_many("t", "c", X, version="v1")
-    assert found is None and miss.all() and len(keys) == 20
-    cache.put_many("t", "c", keys, E, version="v1")
+    look = cache.lookup_many("t", "c", X, version="v1")
+    assert look.found is None and look.miss.all() and len(look.keys) == 20
+    cache.insert_many("t", "c", look.keys, X, E, version="v1")
     # overlapping second chunk: cached rows hit, the new row misses
     X2 = np.concatenate([X[5:], rng.standard_normal((1, 8))
                          .astype(np.float32)])
-    k2, found2, miss2 = cache.get_many("t", "c", X2, version="v1")
-    assert miss2.sum() == 1 and miss2[-1]
-    np.testing.assert_allclose(found2[:-1], E[5:], atol=0)
+    look2 = cache.lookup_many("t", "c", X2, version="v1")
+    assert look2.miss.sum() == 1 and look2.miss[-1]
+    np.testing.assert_allclose(look2.found[:-1], E[5:], atol=0)
     # version partitions the key space
-    _, f3, m3 = cache.get_many("t", "c", X, version="v2")
-    assert f3 is None and m3.all()
+    look3 = cache.lookup_many("t", "c", X, version="v2")
+    assert look3.found is None and look3.miss.all()
     assert cache.stats.hits == 15
-    # single-row wrappers ride the same tier
-    assert cache.get_row("t", "c", X[0], version="v1") is not None
-    np.testing.assert_allclose(cache.get_row("t", "c", X[0],
-                                             version="v1"), E[0])
-    assert cache.get_row("t", "c", np.full(8, 9.0, np.float32),
-                         version="v1") is None
-    cache.put_row("t", "c", np.full(8, 9.0, np.float32),
-                  np.ones(4, np.float32), version="v1")
-    np.testing.assert_allclose(
-        cache.get_row("t", "c", np.full(8, 9.0, np.float32),
-                      version="v1"), np.ones(4))
 
 
 def test_share_cache_single_row_block_stays_bounded():
@@ -212,12 +201,11 @@ def test_share_cache_single_row_block_stays_bounded():
     rng = np.random.default_rng(0)
     for i in range(8):                        # 8 x 32 fresh rows, 1 block
         X = rng.standard_normal((32, 8)).astype(np.float32)
-        keys, _, _ = cache.get_many("t", "c", X)
-        cache.put_many("t", "c", keys, np.ones((32, 4), np.float32))
+        keys = cache.lookup_many("t", "c", X).keys
+        cache.insert_many("t", "c", keys, X, np.ones((32, 4), np.float32))
         assert cache._rows_used <= cache.capacity
         # the newest rows survive the shedding
-        _, _, miss = cache.get_many("t", "c", X)
-        assert not miss.any()
+        assert not cache.lookup_many("t", "c", X).miss.any()
 
 
 def test_share_cache_row_blocks_evict_lru():
@@ -225,12 +213,11 @@ def test_share_cache_row_blocks_evict_lru():
     X = np.arange(64 * 8, dtype=np.float32).reshape(64, 8)
     E = np.ones((64, 4), np.float32)
     for i in range(4):                        # 4 key spaces, LRU evicts
-        keys, _, _ = cache.get_many("t", f"c{i}", X)
-        cache.put_many("t", f"c{i}", keys, E)
-    _, found, miss = cache.get_many("t", "c0", X)
-    assert found is None and miss.all()       # oldest block evicted
-    _, found3, miss3 = cache.get_many("t", "c3", X)
-    assert not miss3.any()                    # newest survives
+        keys = cache.lookup_many("t", f"c{i}", X).keys
+        cache.insert_many("t", f"c{i}", keys, X, E)
+    look = cache.lookup_many("t", "c0", X)
+    assert look.found is None and look.miss.all()   # oldest evicted
+    assert not cache.lookup_many("t", "c3", X).miss.any()  # newest stays
 
 
 _KEYS = [3, 10, 10**6, 2**40, 2**63 + 5]        # sorted, distinct

@@ -451,34 +451,18 @@ def test_distinct_trunks_get_distinct_lanes(tmp_path, serve_zoo, table,
     assert len(server._lanes) == 2
 
 
-def test_share_lanes_match_legacy_task_lanes(tmp_path, serve_zoo, table,
-                                             sample):
-    """The embed/head split must be invisible in the scores."""
-    outs = {}
-    for mode in (True, False):
-        sess = make_session(tmp_path / str(mode), serve_zoo, table)
-        sess.resolve_task("sent", sample.X, sample.y)
-        server = MorphingServer(session=sess, share_lanes=mode)
-        with server:
-            outs[mode] = server.predict(
-                "PREDICT emb USING TASK sent FROM reviews WHERE len > 30",
-                timeout=10.0).scores
-    np.testing.assert_allclose(outs[True], outs[False], atol=1e-5)
-
-
-def test_legacy_lanes_report_no_share_counters(tmp_path, serve_zoo,
-                                               table, sample):
+def test_served_scores_match_session_sql(tmp_path, serve_zoo, table,
+                                         sample):
+    """The embed/head split must be invisible in the scores: a served
+    PREDICT answers what ``session.sql`` answers over the same WHERE."""
+    sql = "PREDICT emb USING TASK sent FROM reviews WHERE len > 30"
     sess = make_session(tmp_path, serve_zoo, table)
     sess.resolve_task("sent", sample.X, sample.y)
-    server = MorphingServer(session=sess, share_lanes=False)
-    with server:
-        for _ in range(2):
-            server.predict("PREDICT emb USING TASK sent FROM reviews",
-                           timeout=10.0)
-    st = server.stats()
-    assert st.share_hits == st.share_misses == st.dedup_rows == 0
-    assert st.embed_rows == 0 and st.head_rows == 0
-    assert st.rows == 1200 and st.share_hit_rate == 0.0
+    ref = sess.sql(sql).rows["_score"]
+    with MorphingServer(session=sess) as server:
+        got = server.predict(sql, timeout=10.0).scores
+    assert len(got) == len(ref) == int((table["len"] > 30).sum())
+    np.testing.assert_allclose(got, ref, atol=1e-5)
 
 
 def test_embed_head_budgets_split(tmp_path, serve_zoo, table, sample):
